@@ -9,8 +9,8 @@ steps (hours on CPU; the same command drives a TPU slice):
 which expands to
 
   python -m repro.launch.train --arch granite-8b --smoke \
-      --layers 8 --d-model 768 --vocab 32768 --pipe 4 --ticks 2 \
-      --steps 300 --batch 8 --seq 256 --lr 5e-3 --mode spectrain \
+      --layers 8 --d-model 768 --vocab 32768 --data uniform --pipe 4 \
+      --ticks 2 --steps 300 --batch 8 --seq 256 --lr 5e-3 --mode spectrain \
       --ckpt-dir /tmp/repro_100m --resume auto
 """
 import os
@@ -25,7 +25,8 @@ SMALL = ["--arch", "granite-8b", "--smoke", "--layers", "4",
          "--lr", "1e-2", "--mode", "spectrain", "--log-every", "20"]
 
 FULL = ["--arch", "granite-8b", "--smoke", "--layers", "8",
-        "--d-model", "768", "--vocab", "32768", "--pipe", "4",
+        "--d-model", "768", "--vocab", "32768", "--data", "uniform",
+        "--pipe", "4",
         "--ticks", "2", "--steps", "300", "--batch", "8", "--seq", "256",
         "--lr", "5e-3", "--mode", "spectrain",
         "--ckpt-dir", "/tmp/repro_100m", "--resume", "auto"]

@@ -176,9 +176,42 @@ class Runtime:
                              ticks_per_step=c.ticks_per_step,
                              plan=self.plan)
 
+    def init(self, key, batch_sds=None) -> Dict[str, Any]:
+        """Train state from a fresh ``model.init(key)``.
+
+        Under ``execution="mpmd"`` the init and packing run as one
+        jitted program whose outputs carry the stage-local shardings,
+        and the flat layer stacks are drawn split over ``pipe``, so each
+        device creates only its own share of the layers and the whole
+        model never sits on one device; the bits are those of the
+        unsplit jitted init.  Other executions build the state as
+        ``init_state(model.init(key), batch_sds)``.
+        """
+        if not (self._ir and self.config.execution == "mpmd"):
+            return self.init_state(self.model.init(key), batch_sds)
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.runtime import sharding as rsh
+
+        mesh = ps._mpmd_mesh(self.mesh, self.plan.n_devices)
+        rows = None
+        if self.model.cfg.n_layers % self.plan.n_devices == 0:
+            rows = NamedSharding(mesh, PartitionSpec("pipe"))
+
+        def build(k):
+            return self.init_state(self.model.init(k, rows), batch_sds)
+
+        shardings = rsh.mpmd_state_shardings(mesh, jax.eval_shape(build, key))
+        return jax.jit(build, out_shardings=shardings)(key)
+
     def train_step(self, state, batch):
         """One training step (round or tick group); built and jitted
         lazily on first call, donated state."""
+        return self.step_fn()(state, batch)
+
+    def step_fn(self) -> Callable:
+        """The step :meth:`train_step` calls: jitted with donated state
+        (untraced), so ``step_fn().lower(state_sds, batch_sds)``
+        compiles it for shapes alone."""
         if self.serving:
             raise TypeError("train_step is a training entry point; "
                             "this Runtime binds a ServePlan — use "
@@ -203,7 +236,7 @@ class Runtime:
             if self.tracer is not None:
                 fn = self.tracer.wrap_step(fn)
             self._step = fn
-        return self._step(state, batch)
+        return self._step
 
     # -------------------------------------------------------------- serving
     def serve_engine(self, params):

@@ -1033,7 +1033,6 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
     attribution is tick-granular: install the groups from
     ``obs.trace.device_stream_tick_groups`` on the tracer.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     sizes = _ir_plan_check(model, plan)
@@ -1043,6 +1042,12 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
     two_buf = max(plan.w_stash_depth) > 1
     mesh = _mpmd_mesh(mesh, S)
     d_head = (C - 1) % S
+    # the outer gradient accumulates per device: head contributions on
+    # d_head, embed ones on device 0.  With S > 1 these differ, so one
+    # outer-sized accumulator per device holds whichever it takes; with
+    # S == 1 the two stay apart so the sums keep the SPMD bodies' order
+    n_outer = 2 if d_head == 0 else 1
+    i_head, i_embed = 0, n_outer - 1
     nv, nc = streams.n_val_slots, streams.n_cot_slots
     lags = sorted({s for _k, _q, s in streams.branches})
     rows = jnp.asarray(streams.rows)          # [T, S, DN_COLS]
@@ -1080,14 +1085,15 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                 outer_rd[s] = base_p["outer"]
         return mbs, stage_rd, outer_rd
 
-    def _post(state, gs_g, goh_g, goe_g, ls_g):
+    def _post(state, gs_g, go_g, ls_g):
         """Round epilogue: combine the per-device outer partials by
         *static indexing* (head lives on device (C-1)%S, embed on
         device 0) — the one cross-device add of the round, in the same
         head+embed order as the SPMD bodies (a psum would add identity
         elements and flip -0.0 bits) — then apply the SGD update."""
         params, mom = state["params"], state["momentum"]
-        go = jax.tree.map(lambda h, e: h[d_head] + e[0], goh_g, goe_g)
+        go = jax.tree.map(lambda h, e: h[d_head] + e[0], go_g[i_head],
+                          go_g[i_embed])
         loss = ls_g[d_head] / M
         grads = {"outer": go, "stages": gs_g}
         grads = jax.tree.map(lambda g: g / M, grads)
@@ -1149,6 +1155,9 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                 return jax.tree.map(
                     lambda a, gg: jnp.where(first, gg, a + gg), acc, g)
 
+            def outer_acc(go, i, g, first):
+                return go[:i] + (first_or_add(go[i], g, first),) + go[i + 1:]
+
             def gs_acc(gs, gw, q, first):
                 # static in-place accumulate of chunk q's ragged grad
                 # into the packed local shard (padding rows untouched)
@@ -1163,7 +1172,7 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
 
             def mk_fwd(q, s):
                 def br(carry, row):
-                    V, Ct, gs, goh, goe, ls = carry
+                    V, Ct, gs, go, ls = carry
                     m = row[sir.DCOL_MB]
                     if q == 0:
                         x = model.embed(ord_l[s], mb(m))
@@ -1179,12 +1188,12 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                         sf = zeros_x()
                     else:
                         sf = out
-                    return (V, Ct, gs, goh, goe, ls), sf, zeros_x()
+                    return (V, Ct, gs, go, ls), sf, zeros_x()
                 return br
 
             def mk_bwd(q, s):
                 def br(carry, row):
-                    V, Ct, gs, goh, goe, ls = carry
+                    V, Ct, gs, go, ls = carry
                     m = row[sir.DCOL_MB]
                     x = jax.lax.dynamic_index_in_dim(
                         V, row[sir.DCOL_A], 0, keepdims=False)
@@ -1196,8 +1205,8 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                             lambda o, xl: model.head_loss(o, xl, tgt),
                             ord_l[s], out)
                         go_head, cot = head_vjp(jnp.ones((), loss_m.dtype))
-                        goh = first_or_add(goh, go_head,
-                                           row[sir.DCOL_FIRST_O] > 0)
+                        go = outer_acc(go, i_head, go_head,
+                                       row[sir.DCOL_FIRST_O] > 0)
                         ls = ls + loss_m
                     else:
                         cot = jax.lax.dynamic_index_in_dim(
@@ -1209,12 +1218,12 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                         _, evjp = jax.vjp(lambda o: model.embed(o, mb(m)),
                                           ord_l[s])
                         (go_embed,) = evjp(gx)
-                        goe = first_or_add(goe, go_embed,
-                                           row[sir.DCOL_FIRST_E] > 0)
+                        go = outer_acc(go, i_embed, go_embed,
+                                       row[sir.DCOL_FIRST_E] > 0)
                         sb = zeros_x()
                     else:
                         sb = gx
-                    return (V, Ct, gs, goh, goe, ls), zeros_x(), sb
+                    return (V, Ct, gs, go, ls), zeros_x(), sb
                 return br
 
             branches = [mk_fwd(q, s) if kind == "fwd" else mk_bwd(q, s)
@@ -1232,14 +1241,14 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                     else sf
                 rb = jax.lax.ppermute(sb, "pipe", bwd_perm) if S > 1 \
                     else sb
-                V, Ct, gs, goh, goe, ls = carry
+                V, Ct, gs, go, ls = carry
                 V = jax.lax.dynamic_update_index_in_dim(
                     V, rf, jnp.where(row[sir.DCOL_RECV_F] >= 0,
                                      row[sir.DCOL_RECV_F], nv), 0)
                 Ct = jax.lax.dynamic_update_index_in_dim(
                     Ct, rb, jnp.where(row[sir.DCOL_RECV_B] >= 0,
                                       row[sir.DCOL_RECV_B], nc), 0)
-                return (V, Ct, gs, goh, goe, ls)
+                return (V, Ct, gs, go, ls)
             return tick
 
         def local_carry0(srd_l, ord_l):
@@ -1247,8 +1256,8 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                 jnp.zeros((nv + 1,) + x_sd.shape, x_sd.dtype),
                 jnp.zeros((nc + 1,) + x_sd.shape, x_sd.dtype),
                 jax.tree.map(jnp.zeros_like, srd_l[lags[0]]),
-                jax.tree.map(jnp.zeros_like, ord_l[lags[0]]),
-                jax.tree.map(jnp.zeros_like, ord_l[lags[0]]),
+                tuple(jax.tree.map(jnp.zeros_like, ord_l[lags[0]])
+                      for _ in range(n_outer)),
                 jnp.zeros((), loss_sd.dtype),
             )
 
@@ -1263,19 +1272,18 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                 def body(carry, row):
                     return tick(carry, row[0]), None
 
-                (_V, _Ct, gs, goh, goe, ls), _ = jax.lax.scan(
+                (_V, _Ct, gs, go, ls), _ = jax.lax.scan(
                     body, local_carry0(srd_l, ord_l), rows_l)
-                return gs, expand(goh), expand(goe), ls[None]
+                return gs, expand(go), ls[None]
 
-            run = shard_map(
+            run = jax.shard_map(
                 round_body, mesh=mesh,
                 in_specs=(P(None, "pipe", None), P(), P(None, "pipe"),
                           P()),
-                out_specs=(P(None, "pipe"), P("pipe"), P("pipe"),
-                           P("pipe")),
-                check_rep=False)
-            gs_g, goh_g, goe_g, ls_g = run(rows, mbs, stage_rd, outer_rd)
-            return _post(state, gs_g, goh_g, goe_g, ls_g)
+                out_specs=(P(None, "pipe"), P("pipe"), P("pipe")),
+                check_vma=False)
+            gs_g, go_g, ls_g = run(rows, mbs, stage_rd, outer_rd)
+            return _post(state, gs_g, go_g, ls_g)
         else:
             # tick-unrolled: one jitted shard_map per tick, a blocking
             # host mark between calls — io_callback is not safe inside
@@ -1292,25 +1300,21 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                     "— call it eagerly (it jits each tick internally)")
             if not _jits:
                 def tick_body(row_l, mbs_l, srd_l, ord_l,
-                              V_l, Ct_l, gs, goh_l, goe_l, ls_l):
+                              V_l, Ct_l, gs, go_l, ls_l):
                     tick = make_tick(mbs_l, srd_l, ord_l)
                     carry = (V_l[0], Ct_l[0], gs,
-                             jax.tree.map(lambda x: x[0], goh_l),
-                             jax.tree.map(lambda x: x[0], goe_l),
-                             ls_l[0])
-                    V, Ct, gs, goh, goe, ls = tick(carry, row_l[0])
-                    return (V[None], Ct[None], gs, expand(goh),
-                            expand(goe), ls[None])
+                             jax.tree.map(lambda x: x[0], go_l), ls_l[0])
+                    V, Ct, gs, go, ls = tick(carry, row_l[0])
+                    return (V[None], Ct[None], gs, expand(go), ls[None])
 
-                _jits["tick"] = jax.jit(shard_map(
+                _jits["tick"] = jax.jit(jax.shard_map(
                     tick_body, mesh=mesh,
                     in_specs=(P("pipe", None), P(), P(None, "pipe"),
                               P(), P("pipe"), P("pipe"),
-                              P(None, "pipe"), P("pipe"), P("pipe"),
-                              P("pipe")),
+                              P(None, "pipe"), P("pipe"), P("pipe")),
                     out_specs=(P("pipe"), P("pipe"), P(None, "pipe"),
-                               P("pipe"), P("pipe"), P("pipe")),
-                    check_rep=False), donate_argnums=(4, 5, 6, 7, 8, 9))
+                               P("pipe"), P("pipe")),
+                    check_vma=False), donate_argnums=(4, 5, 6, 7, 8))
                 # the prologue and epilogue run under their own jits:
                 # eager op-by-op execution would skip the FMA fusion
                 # XLA applies inside the untraced step's single jit and
@@ -1324,14 +1328,14 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
             gs_g = jax.tree.map(jnp.zeros_like, stage_rd[lags[0]])
             big = lambda t: jax.tree.map(
                 lambda x: jnp.zeros((S,) + x.shape, x.dtype), t)
-            goh_g, goe_g = big(outer_rd[lags[0]]), big(outer_rd[lags[0]])
+            go_g = tuple(big(outer_rd[lags[0]]) for _ in range(n_outer))
             ls_g = jnp.zeros((S,), loss_sd.dtype)
             for t in range(T):
-                Vg, Cg, gs_g, goh_g, goe_g, ls_g = run(
+                Vg, Cg, gs_g, go_g, ls_g = run(
                     rows[t], mbs, stage_rd, outer_rd,
-                    Vg, Cg, gs_g, goh_g, goe_g, ls_g)
+                    Vg, Cg, gs_g, go_g, ls_g)
                 jax.block_until_ready(ls_g)
                 tracer._mark()
-            return _jits["post"](state, gs_g, goh_g, goe_g, ls_g)
+            return _jits["post"](state, gs_g, go_g, ls_g)
 
     return step

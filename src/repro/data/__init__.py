@@ -1,2 +1,2 @@
-from repro.data.pipeline import (DataConfig, SyntheticLM,  # noqa: F401
+from repro.data.pipeline import (KINDS, DataConfig, SyntheticLM,  # noqa: F401
                                  make_iterator)

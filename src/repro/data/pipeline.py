@@ -28,10 +28,26 @@ class DataConfig:
     bigram_temp: float = 0.5
 
 
+KINDS = ("bigram", "uniform")
+# the bigram table is V x V float64, built with as many temporaries again;
+# above this it stops being a test fixture and starts swapping the host
+BIGRAM_MAX_TABLE_BYTES = 1 << 30
+
+
 class SyntheticLM:
     def __init__(self, cfg: DataConfig):
         self.cfg = cfg
+        if cfg.kind not in KINDS:
+            raise ValueError(f"unknown data kind {cfg.kind!r}; known: {KINDS}")
         if cfg.kind == "bigram":
+            table = cfg.vocab_size * cfg.vocab_size * 8
+            if table > BIGRAM_MAX_TABLE_BYTES:
+                raise ValueError(
+                    f"a bigram stream over vocab {cfg.vocab_size} needs a "
+                    f"{table / 2**30:.1f} GiB V x V table (limit "
+                    f"{BIGRAM_MAX_TABLE_BYTES / 2**30:.0f} GiB); use "
+                    f"--data uniform (DataConfig(kind='uniform')) at "
+                    f"published vocabulary sizes")
             rng = np.random.Generator(np.random.Philox(key=cfg.seed))
             logits = rng.normal(size=(cfg.vocab_size, cfg.vocab_size))
             logits = logits / cfg.bigram_temp

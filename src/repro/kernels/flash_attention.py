@@ -25,10 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
-
 NEG_INF = -1e30
 
 
@@ -62,20 +58,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         mask = mask & (qpos >= kpos)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_scr[...]                           # [bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1)
+    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
     v = v_ref[0, 0].astype(jnp.float32)
-    acc_scr[...] = corr[:, None] * acc_scr[...] + jax.lax.dot_general(
+    acc_scr[...] = corr * acc_scr[...] + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _done():
         lsum = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / lsum[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / lsum).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[...] + jnp.log(lsum)
 
 
@@ -84,8 +80,11 @@ def flash_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """q: [b, h, sq_folded, d] (GQA pre-folded); k, v: [b, h, sk, d].
 
-    Returns (o, lse).  ``sq_folded = G * sq`` when folding; causal masking
-    recovers positions as ``row % sq`` with sq == sk."""
+    Returns (o, lse) with lse [b, h, sq_folded, 1]: the trailing unit
+    axis keeps its block's last two dims legal for the TPU compiler
+    ((block_q, 1): divisible by 8 / equal to the array's dim).
+    ``sq_folded = G * sq`` when folding; causal masking recovers
+    positions as ``row % sq`` with sq == sk."""
     b, h, sqf, d = q.shape
     sk = k.shape[2]
     sq = sk if causal else sqf
@@ -110,18 +109,19 @@ def flash_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sqf, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sqf), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sqf, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -158,10 +158,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     mask = kpos < sk
     if causal:
         mask = mask & (jnp.remainder(rows, sq) >= kpos)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - dl[:, None]) * scale
+    ds = p * (dp - dl) * scale
     acc_scr[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -201,12 +201,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     mask = kpos < sk
     if causal:
         mask = mask & (jnp.remainder(rows, sq) >= kpos)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dv_scr[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - dl[:, None]) * scale
+    ds = p * (dp - dl) * scale
     dk_scr[...] += jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -225,7 +225,8 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True,
     nq = (sqf + block_q - 1) // block_q
     nk = (sk + block_k - 1) // block_k
     scale = 1.0 / math.sqrt(d)
-    dl = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    dl = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
+                 keepdims=True)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -241,14 +242,16 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True,
                          lambda b, h, iq, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -268,8 +271,10 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True,
                          lambda b, h, ik, iq: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, ik, iq: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, ik, iq: (b, h, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d),
@@ -281,7 +286,7 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = True,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
-
 _EPS = 1e-24
 
 
@@ -35,30 +31,30 @@ def _ssd_kernel(x_ref, dt_ref, de_ref, b_ref, c_ref, s0_ref,
         s_scr[...] = s0_ref[0, 0].astype(jnp.float32)
 
     x = x_ref[0, 0].astype(jnp.float32)        # [C, p]
-    dt = dt_ref[0, 0].astype(jnp.float32)      # [C]
-    de = de_ref[0, 0].astype(jnp.float32)      # [C] decay in (0,1]
+    dt = dt_ref[0, 0].astype(jnp.float32)      # [C, 1]
+    de = de_ref[0, 0].astype(jnp.float32)      # [C, 1] decay in (0,1]
     B = b_ref[0, 0].astype(jnp.float32)        # [C, n]
     C = c_ref[0, 0].astype(jnp.float32)        # [C, n]
     S = s_scr[...]                             # [p, n]
 
-    cp = jnp.cumprod(de, axis=0)               # inclusive [C]
-    dtx = dt[:, None] * x                      # [C, p]
+    cp = jnp.cumprod(de, axis=0)               # inclusive [C, 1]
+    dtx = dt * x                               # [C, p]
 
     score = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    ratio = cp[:, None] / jnp.maximum(cp[None, :], _EPS)
+    ratio = cp / jnp.maximum(cp.T, _EPS)
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     score = jnp.where(rows >= cols, score * ratio, 0.0)
 
     y_intra = jax.lax.dot_general(score, dtx, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y_state = cp[:, None] * jax.lax.dot_general(
+    y_state = cp * jax.lax.dot_general(
         C, S, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     y_ref[0, 0] = (y_intra + y_state).astype(y_ref.dtype)
 
-    cp_last = cp[-1]
-    tail = (cp_last / jnp.maximum(cp, _EPS))[:, None] * dtx   # [C, p]
+    cp_last = cp[-1:]                          # [1, 1]
+    tail = (cp_last / jnp.maximum(cp, _EPS)) * dtx   # [C, p]
     S_new = cp_last * S + jax.lax.dot_general(
         tail, B, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -72,7 +68,10 @@ def _ssd_kernel(x_ref, dt_ref, de_ref, b_ref, c_ref, s0_ref,
 def mamba2_scan(x, dt, decay, B, C, S0, *, chunk: int = 32,
                 interpret: bool = False):
     """x: [b,h,s,p]; dt,decay: [b,h,s]; B,C: [b,h,s,n]; S0: [b,h,p,n] fp32.
-    Returns (y [b,h,s,p], S_T fp32)."""
+    Returns (y [b,h,s,p], S_T fp32).
+
+    ``dt``/``decay`` enter the kernel as [b,h,s,1] so their (chunk, 1)
+    blocks meet the TPU compiler's last-two-dims rule."""
     b, h, s, p = x.shape
     n = B.shape[-1]
     assert s % chunk == 0, (s, chunk)
@@ -83,8 +82,8 @@ def mamba2_scan(x, dt, decay, B, C, S0, *, chunk: int = 32,
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, ic: (b, h, ic)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, ic: (b, h, ic)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, ic: (b, h, ic, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, p, n), lambda b, h, ic: (b, h, 0, 0)),
@@ -98,8 +97,8 @@ def mamba2_scan(x, dt, decay, B, C, S0, *, chunk: int = 32,
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, decay, B, C, S0)
+    )(x, dt[..., None], decay[..., None], B, C, S0)
     return y, sT

@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
-
 _EPS = 1e-24
 
 
@@ -45,7 +41,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)           # [hd]
+    u = u_ref[0].astype(jnp.float32)           # [1, hd]
     S = s_scr[...]                             # [hd_k, hd_v]
 
     cp = jnp.cumprod(w, axis=0)                # inclusive products
@@ -58,7 +54,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     score = jnp.where(rows > cols, score, 0.0)
-    diag = jnp.sum(r * u[None, :] * k, axis=1)           # bonus term
+    diag = jnp.sum(r * u * k, axis=1)                    # bonus term
     score = score + jnp.where(rows == cols, diag[:, None], 0.0)
 
     y_intra = jax.lax.dot_general(score, v, (((1,), (0,)), ((), ())),
@@ -82,7 +78,10 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
 def rwkv6_scan(r, k, v, w, u, S0, *, chunk: int = 32,
                interpret: bool = False):
     """r,k,v,w: [b, h, s, hd]; u: [h, hd]; S0: [b, h, hd, hd] fp32.
-    Returns (y [b,h,s,hd] fp32-accurate in r.dtype, S_T fp32)."""
+    Returns (y [b,h,s,hd] fp32-accurate in r.dtype, S_T fp32).
+
+    ``u`` enters the kernel as [h, 1, hd] so its (1, hd) block spans the
+    array's last two dims, as the TPU compiler requires."""
     b, h, s, hd = r.shape
     assert s % chunk == 0, (s, chunk)
     nc = s // chunk
@@ -95,7 +94,7 @@ def rwkv6_scan(r, k, v, w, u, S0, *, chunk: int = 32,
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, hd), lambda b, h, ic: (h, 0)),
+            pl.BlockSpec((1, 1, hd), lambda b, h, ic: (h, 0, 0)),
             pl.BlockSpec((1, 1, hd, hd), lambda b, h, ic: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -107,8 +106,8 @@ def rwkv6_scan(r, k, v, w, u, S0, *, chunk: int = 32,
             jax.ShapeDtypeStruct((b, h, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u, S0)
+    )(r, k, v, w, u.reshape(h, 1, hd), S0)
     return y, sT

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.api import add_runtime_args, runtime_config_from_args, Runtime
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.obs import MetricsRegistry
 from repro.planner import serve_plan
@@ -42,6 +43,7 @@ def _pair(s: str):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--pipe", type=int, default=2,

@@ -38,15 +38,17 @@ import argparse
 import dataclasses
 import json
 import time
+from typing import Any, Dict, NamedTuple
 
 import jax
 import numpy as np
 
-from repro.api import (Runtime, add_runtime_args,
+from repro.api import (Runtime, RuntimeConfig, add_runtime_args,
                        runtime_config_from_args)
 from repro.configs import get_config, smoke_config
 from repro.core import pipeline_stream, pipeline_sync
-from repro.data import DataConfig, SyntheticLM
+from repro.data import KINDS, DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.obs import (MetricsRegistry, PipelineTracer,
                        device_stream_tick_groups, drift_report,
@@ -78,7 +80,7 @@ def build(args):
     return cfg
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--smoke", action="store_true")
@@ -90,6 +92,10 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
+    ap.add_argument("--data", default="bigram", choices=KINDS,
+                    help="synthetic token stream: 'bigram' (learnable, "
+                         "V x V table, small vocabularies only) or "
+                         "'uniform' (i.i.d. tokens, any vocabulary)")
     add_runtime_args(ap)
     ap.add_argument("--virtual-stages", type=int, default=1,
                     dest="virtual_stages",
@@ -117,7 +123,24 @@ def main(argv=None) -> int:
     ap.add_argument("--metrics-out", default="", dest="metrics_out",
                     help="append structured JSONL telemetry (step records, "
                          "heartbeat/restate events, summary) to this path")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+class Setup(NamedTuple):
+    """What a run builds before it creates any state."""
+    rc: RuntimeConfig
+    cfg: Any
+    model: Model
+    data: SyntheticLM
+    batch_sds: Dict[str, jax.ShapeDtypeStruct]
+    plan: Any
+    schedule: str
+
+
+def setup(args) -> Setup:
+    """Config, model, data stream, plan and RuntimeConfig for parsed
+    flags; prints the ``# plan`` lines.  Shared by :func:`main` and
+    the chip smoke run, which compiles the same step programs."""
     try:
         rc = runtime_config_from_args(args,
                                       ticks_per_step=max(args.ticks, 1))
@@ -126,12 +149,13 @@ def main(argv=None) -> int:
 
     cfg = build(args)
     model = Model(cfg)
-    data = SyntheticLM(DataConfig(cfg.vocab_size, args.seq, args.batch,
-                                  seed=args.seed))
-    key = jax.random.PRNGKey(args.seed)
-    batch0 = data.batch_at(0)
+    try:
+        data = SyntheticLM(DataConfig(cfg.vocab_size, args.seq, args.batch,
+                                      seed=args.seed, kind=args.data))
+    except ValueError as e:
+        raise SystemExit(str(e))
     batch_sds = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch0)
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), data.batch_at(0))
 
     # profile-guided plan: partition + IR-derived staleness for the
     # schedule this run executes (gpipe for the sync fill/drain pipeline,
@@ -193,7 +217,9 @@ def main(argv=None) -> int:
         partitioner=args.partitioner, profile_method=args.profile_method,
         batch=args.batch, seq=args.seq, **plan_kw)
     check_against_closed_forms(pplan)
-    print(f"# {pplan.summary()}")
+    prof = pplan.profile
+    fell_back = f" ({prof.fallback})" if prof.fallback else ""
+    print(f"# {pplan.summary()} profile={prof.method}{fell_back}")
     stage_desc = " ".join(
         f"s{k}:L[{lo}:{hi})={c:.2e}s"
         for k, ((lo, hi), c) in enumerate(zip(pplan.stage_ranges,
@@ -206,6 +232,14 @@ def main(argv=None) -> int:
               f"microbatches, bubble={pplan.bubble_frac:.3f}, "
               f"act_stash={pplan.act_stash}, "
               f"w_stash_depth={pplan.w_stash_depth}")
+    return Setup(rc, cfg, model, data, batch_sds, pplan, schedule)
+
+
+def main(argv=None) -> int:
+    enable_compile_cache()
+    args = parse_args(argv)
+    rc, cfg, model, data, batch_sds, pplan, schedule = setup(args)
+    key = jax.random.PRNGKey(args.seed)
 
     registry = MetricsRegistry(jsonl_path=args.metrics_out or None)
     if args.metrics_out:
@@ -226,7 +260,7 @@ def main(argv=None) -> int:
         # the Runtime facade owns jit/donation (and the traced-mpmd
         # per-tick exception) for both schedule families
         rt = Runtime(pplan, model, rc, tracer=tracer)
-        state = rt.init_state(model.init(key), batch_sds)
+        state = rt.init(key, batch_sds)
         if tracer is not None and rc.execution == "mpmd":
             # the mpmd round runs T device-stream ticks, not one host
             # mark per compute event — map tick marks back onto the

@@ -215,11 +215,18 @@ class Model:
                                            self.n_stages, "stage")
         return {"outer": self._outer_specs(), "stages": stages}
 
-    def init(self, key):
+    def init(self, key, layers_sharding=None):
+        """Random parameters in the canonical layout.  Under ``jit``,
+        ``layers_sharding`` places the flat ``[n_layers, ...]`` layer
+        stacks as they are drawn (the draw is partitionable, so each
+        device then computes only its own rows, bit-identically)."""
         params = init_params(self._flat_param_specs(), key,
                              self.cfg.param_dtype)
         if self.cfg.is_encdec:
             return params
+        if layers_sharding is not None:
+            params["stages"]["layers"] = jax.lax.with_sharding_constraint(
+                params["stages"]["layers"], layers_sharding)
         return {"outer": params["outer"],
                 "stages": split_flat_stages(params["stages"],
                                             self.stage_sizes)}

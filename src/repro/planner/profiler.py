@@ -45,6 +45,9 @@ class ModelProfile:
     batch: int
     seq: int
     layers: Tuple[LayerProfile, ...]
+    # why ``method`` is not the one asked for ("" when it is): "auto"
+    # falls back from "hlo" to "analytic" and says so here
+    fallback: str = ""
 
     @property
     def n_layers(self) -> int:
@@ -151,20 +154,21 @@ def profile_model(cfg, *, batch: int = 1, seq: int = 32,
     """Per-layer profile for an ArchConfig (one entry per layer)."""
     if method not in METHODS:
         raise ValueError(f"unknown profile method {method!r}")
-    used = method
+    used, fallback = method, ""
     if method in ("auto", "hlo"):
         try:
             layer = _hlo_layer(cfg, batch, seq)
             used = "hlo"
-        except Exception:
+        except Exception as e:
             if method == "hlo":
                 raise
             layer = _analytic_layer(cfg, batch, seq)
             used = "analytic"
+            fallback = f"hlo failed: {type(e).__name__}: {e}"
     elif method == "timed":
         layer = _timed_layer(cfg, batch, seq)
     else:
         layer = _analytic_layer(cfg, batch, seq)
     layers = tuple(replace(layer, name=f"block{j}")
                    for j in range(cfg.n_layers))
-    return ModelProfile(cfg.name, used, batch, seq, layers)
+    return ModelProfile(cfg.name, used, batch, seq, layers, fallback)

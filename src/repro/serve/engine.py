@@ -265,7 +265,6 @@ def make_mpmd_round(model, streams: sir.ServeStreams, init_pages,
     ``ppermute`` every tick, incoming payloads park in the row's
     receive slot (-1 -> the trash slot).  Emitted tokens surface on
     the last device; index ``[S - 1]`` of the pipe-stacked outputs."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P_
 
     C, F, S = streams.n_chunks, streams.max_prefill, streams.n_devices
@@ -396,13 +395,13 @@ def make_mpmd_round(model, streams: sir.ServeStreams, init_pages,
             tick, carry, rows_l)
         return dec_next[None], pf_next[None], pc_l
 
-    run = shard_map(
+    run = jax.shard_map(
         round_body, mesh=mesh,
         in_specs=(P_(None, "pipe"), P_(), P_("pipe"),
                   P_(None, "pipe", None), P_(), P_(), P_(), P_(), P_(),
                   P_()),
         out_specs=(P_("pipe"), P_("pipe"), P_("pipe")),
-        check_rep=False)
+        check_vma=False)
 
     def round_fn(packed_params, outer, packed_caches, dec_tokens,
                  dec_pos, dec_pages, pf_tokens, pf_len, pf_pages):

@@ -1,8 +1,10 @@
 import os
 import sys
 
-# src layout import without install
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# src layout import without install; the root holds chip_smoke.py
+_ROOT = os.path.join(os.path.dirname(__file__), "..")
+sys.path.insert(0, os.path.join(_ROOT, "src"))
+sys.path.insert(1, _ROOT)
 
 
 import jax  # noqa: E402
