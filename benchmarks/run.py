@@ -26,7 +26,7 @@ def main() -> int:
     from benchmarks import (breakdown, comm_time, comm_volume, convergence,
                             ir_compile, kernel_bench, planner_bench, rmse,
                             roofline, serve_bench, throughput,
-                            trace_overhead, verifier_bench)
+                            verifier_bench)
     benches = {
         "comm_volume": comm_volume.main,      # Fig. 3
         "comm_time": comm_time.main,          # Fig. 4
@@ -38,7 +38,6 @@ def main() -> int:
         "roofline": roofline.main,            # EXPERIMENTS.md §Roofline
         "planner": planner_bench.main,        # EXPERIMENTS.md §Planner
         "ir_compile": ir_compile.main,        # EXPERIMENTS.md §IR backends
-        "trace_overhead": trace_overhead.main,  # docs/OBSERVABILITY.md
         "verifier": verifier_bench.main,      # planner/verify.py gate
         "serve": serve_bench.main,            # docs/SERVING.md
     }

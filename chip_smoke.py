@@ -166,14 +166,13 @@ def run_train_phase(phase: str, argv):
     check_losses(f"phase {phase}", losses, first_valid=first_valid,
                  vocab=vocab)
     # each logged step ends in float(loss), which waits for the device;
-    # tok_per_s is cumulative from the first step, so its inverse gives
-    # the time since then, and the first step includes the compile
-    since = [(i + 1) * args.batch * args.seq / r["tok_per_s"]
-             for i, r in enumerate(recs)]
+    # tok_per_s counts the steps after the first (which compiles) from
+    # that step's end, so its inverse gives the time since then
+    since = [i * args.batch * args.seq / r["tok_per_s"]
+             for i, r in enumerate(recs) if i]
     steps_s = [round(b - a, 3) for a, b in zip([0.0] + since, since)]
     print(f"# smoke phase {phase}: {args.steps} steps in {wall:.1f} s, "
-          f"seconds per step {steps_s} (the first includes compile), "
-          f"losses {losses}")
+          f"seconds per step after the first {steps_s}, losses {losses}")
     return losses
 
 

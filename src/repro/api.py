@@ -3,8 +3,8 @@ workloads.
 
 ``RuntimeConfig`` is the single frozen bag of execution knobs that used
 to sprawl across nine keyword arguments on ``make_ir_state`` /
-``make_ir_train_step`` (mode, lr, gamma, clip, backend, tracer,
-execution, mesh, verify); ``Runtime`` binds it to a planner artifact
+``make_ir_train_step`` (mode, lr, gamma, clip, backend, execution,
+mesh, verify); ``Runtime`` binds it to a planner artifact
 and a model and exposes the two workloads:
 
     rt = Runtime(plan, model, RuntimeConfig(mode="spectrain", lr=2e-2))
@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 
 from repro.core import pipeline_stream as ps
+from repro.obs import phases
 
 _SCHEDULES = ("stream",) + ps.IR_SCHEDULES
 
@@ -57,8 +58,6 @@ class RuntimeConfig:
                    IR training rounds and serving rounds.
     ``verify``     statically verify compiled schedule artifacts
                    before execution (``planner/verify.py``).
-    ``trace``      instrument steps for the pipeline tracer (a tracer
-                   instance is passed to :class:`Runtime` separately).
     ``lr/gamma/clip/ticks_per_step``  optimizer and tick knobs the
                    training step consumes; serving ignores them.
     """
@@ -67,7 +66,6 @@ class RuntimeConfig:
     backend: str = "scan"
     execution: str = "spmd"
     verify: bool = True
-    trace: bool = False
     lr: float = 1e-2
     gamma: float = 0.9
     clip: Optional[float] = None
@@ -110,9 +108,9 @@ class Runtime:
 
     Training (``plan`` is a :class:`~repro.planner.PipelinePlan`):
     :meth:`init_state` builds the schedule's train state from canonical
-    init params and :meth:`train_step` executes one round/tick step —
-    jitted with state donation exactly as the launchers did, except
-    under the traced MPMD round, which jits per tick internally.
+    init params and :meth:`train_step` executes one round/tick step,
+    jitted with state donation; its first call records the step's
+    argument shapes for :func:`repro.obs.phases.step_programs`.
 
     Serving (``plan`` is a :class:`~repro.planner.ServePlan`):
     :meth:`serve_engine` builds the continuous-batching
@@ -122,7 +120,7 @@ class Runtime:
     """
 
     def __init__(self, plan, model, config: Optional[RuntimeConfig]
-                 = None, *, tracer=None, mesh=None, registry=None):
+                 = None, *, mesh=None, registry=None):
         from repro.planner.api import PipelinePlan, ServePlan
         if not isinstance(plan, (PipelinePlan, ServePlan)):
             raise TypeError(
@@ -130,7 +128,7 @@ class Runtime:
                 f"got {type(plan).__name__}")
         self.plan, self.model = plan, model
         self.config = config if config is not None else RuntimeConfig()
-        self.tracer, self.mesh, self.registry = tracer, mesh, registry
+        self.mesh, self.registry = mesh, registry
         self.serving = isinstance(plan, ServePlan)
         if not self.serving:
             if self.config.schedule is not None \
@@ -145,10 +143,8 @@ class Runtime:
                     "execution='mpmd' runs IR round schedules "
                     f"({'/'.join(ps.IR_SCHEDULES)}); this plan's "
                     f"schedule is {plan.schedule!r}")
-        if tracer is not None and not self.config.trace:
-            raise ValueError("a tracer was passed but config.trace is "
-                             "False; set RuntimeConfig(trace=True)")
         self._step: Optional[Callable] = None
+        self._step_args = None
         self._engine = None
 
     # ------------------------------------------------------------- training
@@ -206,12 +202,23 @@ class Runtime:
     def train_step(self, state, batch):
         """One training step (round or tick group); built and jitted
         lazily on first call, donated state."""
-        return self.step_fn()(state, batch)
+        fn = self.step_fn()
+        if self._step_args is None:
+            self._step_args = phases.abstract((state, batch))
+            phases.register(self)
+        return fn(state, batch)
+
+    def compiled_step(self):
+        """The program :meth:`train_step` ran, compiled again from the
+        shapes of its first call."""
+        if self._step_args is None:
+            raise ValueError("train_step has not run yet")
+        return self.step_fn().lower(*self._step_args).compile()
 
     def step_fn(self) -> Callable:
-        """The step :meth:`train_step` calls: jitted with donated state
-        (untraced), so ``step_fn().lower(state_sds, batch_sds)``
-        compiles it for shapes alone."""
+        """The step :meth:`train_step` calls: jitted with donated state,
+        so ``step_fn().lower(state_sds, batch_sds)`` compiles it for
+        shapes alone."""
         if self.serving:
             raise TypeError("train_step is a training entry point; "
                             "this Runtime binds a ServePlan — use "
@@ -222,20 +229,14 @@ class Runtime:
                 fn = ps.make_ir_train_step(
                     self.model, plan=self.plan, mode=c.mode, lr=c.lr,
                     gamma=c.gamma, clip=c.clip, backend=c.backend,
-                    tracer=self.tracer, execution=c.execution,
-                    mesh=self.mesh, verify=c.verify)
+                    execution=c.execution, mesh=self.mesh,
+                    verify=c.verify)
             else:
                 fn = ps.make_train_step(
                     self.model, mode=c.mode, lr=c.lr, gamma=c.gamma,
                     clip=c.clip, ticks_per_step=c.ticks_per_step,
                     plan=self.plan)
-            # the traced mpmd round jits per tick and measures wall
-            # time on the host; an outer jit would swallow its marks
-            if not (c.execution == "mpmd" and self.tracer is not None):
-                fn = jax.jit(fn, donate_argnums=0)
-            if self.tracer is not None:
-                fn = self.tracer.wrap_step(fn)
-            self._step = fn
+            self._step = jax.jit(fn, donate_argnums=0)
         return self._step
 
     # -------------------------------------------------------------- serving
@@ -345,7 +346,5 @@ def runtime_config_from_args(args, **overrides) -> RuntimeConfig:
         kw["gamma"] = args.gamma
     if hasattr(args, "clip"):
         kw["clip"] = args.clip or None
-    if getattr(args, "trace", ""):
-        kw["trace"] = True
     kw.update(overrides)
     return RuntimeConfig(**kw)

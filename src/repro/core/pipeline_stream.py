@@ -65,7 +65,6 @@ trees; device d of the S devices hosts chunks ``d, d+S, …``
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -73,6 +72,7 @@ import jax.numpy as jnp
 
 from repro.core import spectrain as st
 from repro.models.layers import shard_act
+from repro.obs import phases
 from repro.optim import sgd
 
 MODES = ("vanilla", "pipedream", "spectrain")
@@ -161,9 +161,11 @@ def _per_stage_gather(ring, idx_vec):
 
 def _predict_stages(stage_trees, mom_trees, lr, s_fwd_v):
     """Eq. 4 per stage tree with that stage's (python int) distance."""
-    return tuple(
-        st.predict_weights(w, v, lr, s)
-        for w, v, s in zip(stage_trees, mom_trees, s_fwd_v))
+    out = []
+    for k, (w, v, s) in enumerate(zip(stage_trees, mom_trees, s_fwd_v)):
+        with phases.stage(k):
+            out.append(st.predict_weights(w, v, lr, s))
+    return tuple(out)
 
 
 def make_state(model, params, batch_sds, *, mode: str = "spectrain",
@@ -283,11 +285,12 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
     def step_degenerate(state, batch):
         loss, grads = jax.value_and_grad(
             lambda p: model.loss(p, batch))(state["params"])
-        if clip:
-            grads, _ = sgd.clip_by_global_norm(grads, clip)
-        params, mom = sgd.update(state["params"],
-                                 sgd.MomentumState(state["momentum"]),
-                                 grads, lr=lr, gamma=gamma)
+        with phases.scope("update"):
+            if clip:
+                grads, _ = sgd.clip_by_global_norm(grads, clip)
+            params, mom = sgd.update(state["params"],
+                                     sgd.MomentumState(state["momentum"]),
+                                     grads, lr=lr, gamma=gamma)
         return ({**state, "params": params, "momentum": mom.v,
                  "step": state["step"] + 1},
                 {"loss": loss, "loss_valid": jnp.ones((), jnp.float32)})
@@ -308,92 +311,107 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
             stages_f = state["pred"]["stages"]
             outer_embed_f = state["pred"]["outer"]
         elif mode == "spectrain":
-            stages_f = _predict_stages(stages, mom_stages, lr, s_fwd_v)
-            outer_embed_f = st.predict_weights(outer, mom_outer, lr,
-                                               s_fwd_embed)
+            with phases.scope("predict"):
+                stages_f = _predict_stages(stages, mom_stages, lr, s_fwd_v)
+                outer_embed_f = st.predict_weights(outer, mom_outer, lr,
+                                                   s_fwd_embed)
         else:
             stages_f, outer_embed_f = stages, outer
 
         # ---------- inject + forward all stages --------------------------
-        x_new = model.embed(outer_embed_f, batch)
-        A = state["fwd_buf"].at[0].set(x_new)
-        A = shard_act(A, "stage", "act_batch", None, None)
-        outs = [stage_fn(stages_f[k], A[k]) for k in range(S)]
-        out = jnp.stack([o for o, _aux in outs])
+        with phases.scope("forward"):
+            x_new = model.embed(outer_embed_f, batch)
+            A = state["fwd_buf"].at[0].set(x_new)
+            A = shard_act(A, "stage", "act_batch", None, None)
+            outs = []
+            for k in range(S):
+                with phases.stage(k):
+                    outs.append(stage_fn(stages_f[k], A[k]))
+            out = jnp.stack([o for o, _aux in outs])
 
-        slot = jnp.mod(t, R)
-        stash = jax.lax.dynamic_update_index_in_dim(
-            state["stash_x"], A, slot, 1)
-        batch_ring = _ring_write(state["batch_ring"], slot, batch)
+        with phases.scope("transfer"):
+            slot = jnp.mod(t, R)
+            stash = jax.lax.dynamic_update_index_in_dim(
+                state["stash_x"], A, slot, 1)
+            batch_ring = _ring_write(state["batch_ring"], slot, batch)
 
         # ---------- head loss at the last stage ---------------------------
-        valid_head = (t >= (S - 1)).astype(jnp.float32)
-        tgt = _ring_read(batch_ring, jnp.mod(t - (S - 1), R))["targets"]
+        with phases.scope("head"):
+            valid_head = (t >= (S - 1)).astype(jnp.float32)
+            tgt = _ring_read(batch_ring, jnp.mod(t - (S - 1), R))["targets"]
 
-        loss, head_vjp = jax.vjp(
-            lambda outer_, xlast: model.head_loss(outer_, xlast, tgt),
-            outer, out[S - 1])
-        g_outer_head, cot_last = head_vjp(valid_head)
+            loss, head_vjp = jax.vjp(
+                lambda outer_, xlast: model.head_loss(outer_, xlast, tgt),
+                outer, out[S - 1])
+            g_outer_head, cot_last = head_vjp(valid_head)
 
         # ---------- backward all stages ------------------------------------
-        valid_b = ((t - lag_vec) >= 0)
-        B_cot = state["bwd_buf"].at[S - 1].set(cot_last)
-        B_cot = B_cot * valid_b[:, None, None, None].astype(B_cot.dtype)
-        idx = jnp.mod(t - g_vec, R)
-        X_b = _per_stage_gather(stash, idx)
-        aux_cot = valid_b.astype(jnp.float32)
+        with phases.scope("backward"):
+            valid_b = ((t - lag_vec) >= 0)
+            B_cot = state["bwd_buf"].at[S - 1].set(cot_last)
+            B_cot = B_cot * valid_b[:, None, None, None].astype(B_cot.dtype)
+            idx = jnp.mod(t - g_vec, R)
+            X_b = _per_stage_gather(stash, idx)
+            aux_cot = valid_b.astype(jnp.float32)
 
-        if mode == "pipedream":
-            stages_b = tuple(_ring_read(state["w_stash"][k], idx[k])
-                             for k in range(S))
-        else:
-            stages_b = stages
-        if bwd_dtype is not None:
-            bdt = jnp.dtype(bwd_dtype)
-            stages_b = tuple(jax.tree.map(lambda p: p.astype(bdt), t_)
-                             for t_ in stages_b)
-        gW, gXs = [], []
-        for k in range(S):
-            _, vjp_k = jax.vjp(stage_fn, stages_b[k], X_b[k])
-            gw_k, gx_k = vjp_k((B_cot[k], aux_cot[k]))
-            gW.append(gw_k)
-            gXs.append(gx_k)
-        gX = jnp.stack(gXs)
+            if mode == "pipedream":
+                stages_b = tuple(_ring_read(state["w_stash"][k], idx[k])
+                                 for k in range(S))
+            else:
+                stages_b = stages
+            if bwd_dtype is not None:
+                bdt = jnp.dtype(bwd_dtype)
+                stages_b = tuple(jax.tree.map(lambda p: p.astype(bdt), t_)
+                                 for t_ in stages_b)
+            gW, gXs = [], []
+            for k in range(S):
+                with phases.stage(k):
+                    _, vjp_k = jax.vjp(stage_fn, stages_b[k], X_b[k])
+                    gw_k, gx_k = vjp_k((B_cot[k], aux_cot[k]))
+                gW.append(gw_k)
+                gXs.append(gx_k)
+            gX = jnp.stack(gXs)
 
-        # ---------- embed backward -----------------------------------------
-        old_batch = _ring_read(batch_ring, jnp.mod(t - lag_vec[0], R))
-        _, evjp = jax.vjp(lambda o: model.embed(o, old_batch), outer)
-        (g_outer_embed,) = evjp(gX[0] * valid_b[0].astype(gX.dtype))
-
-        g_outer = jax.tree.map(jnp.add, g_outer_head, g_outer_embed)
-        grads = {"outer": g_outer, "stages": tuple(gW)}
-        if clip:
-            grads, _ = sgd.clip_by_global_norm(grads, clip)
+            # ---------- embed backward -------------------------------------
+            old_batch = _ring_read(batch_ring, jnp.mod(t - lag_vec[0], R))
+            _, evjp = jax.vjp(lambda o: model.embed(o, old_batch), outer)
+            (g_outer_embed,) = evjp(gX[0] * valid_b[0].astype(gX.dtype))
 
         # ---------- per-tick, per-stage update ------------------------------
-        new_params, new_mom = sgd.update(
-            params, sgd.MomentumState(mom), grads, lr=lr, gamma=gamma)
-        new_pred = None
-        if fused_predict:
-            # Eq. 4 evaluated inside the update pass (the fused_update
-            # kernel's schedule): for tick t+1, Ŵ = W_{t+1} − s·η·v_t.
-            cdt = jnp.dtype(model.cfg.compute_dtype)
-            new_pred = {
-                "stages": tuple(
-                    jax.tree.map(lambda p: p.astype(cdt), t_)
-                    for t_ in _predict_stages(new_params["stages"],
-                                              new_mom.v["stages"],
-                                              lr, s_fwd_v)),
-                "outer": jax.tree.map(
-                    lambda p: p.astype(cdt),
-                    st.predict_weights(new_params["outer"],
-                                       new_mom.v["outer"], lr,
-                                       s_fwd_embed)),
-            }
+        with phases.scope("update"):
+            g_outer = jax.tree.map(jnp.add, g_outer_head, g_outer_embed)
+            grads = {"outer": g_outer, "stages": tuple(gW)}
+            if clip:
+                grads, _ = sgd.clip_by_global_norm(grads, clip)
+            new_params, new_mom = sgd.update(
+                params, sgd.MomentumState(mom), grads, lr=lr, gamma=gamma)
+            new_pred = None
+            if fused_predict:
+                # Eq. 4 evaluated inside the update pass (the fused_update
+                # kernel's schedule): for tick t+1, Ŵ = W_{t+1} − s·η·v_t.
+                cdt = jnp.dtype(model.cfg.compute_dtype)
+                new_pred = {
+                    "stages": tuple(
+                        jax.tree.map(lambda p: p.astype(cdt), t_)
+                        for t_ in _predict_stages(new_params["stages"],
+                                                  new_mom.v["stages"],
+                                                  lr, s_fwd_v)),
+                    "outer": jax.tree.map(
+                        lambda p: p.astype(cdt),
+                        st.predict_weights(new_params["outer"],
+                                           new_mom.v["outer"], lr,
+                                           s_fwd_embed)),
+                }
 
         # ---------- rotate in-flight buffers --------------------------------
-        A_next = jnp.roll(out, 1, axis=0)
-        B_next = jnp.roll(gX, -1, axis=0)
+        with phases.scope("transfer"):
+            A_next = jnp.roll(out, 1, axis=0)
+            B_next = jnp.roll(gX, -1, axis=0)
+            w_stash = None
+            if mode == "pipedream":
+                w_stash = tuple(
+                    _ring_write(state["w_stash"][k], slot, stages[k])
+                    for k in range(S))
 
         new_state = {
             **state,
@@ -402,10 +420,8 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
             "fwd_buf": A_next, "bwd_buf": B_next,
             "stash_x": stash, "batch_ring": batch_ring,
         }
-        if mode == "pipedream":
-            new_state["w_stash"] = tuple(
-                _ring_write(state["w_stash"][k], slot, stages[k])
-                for k in range(S))
+        if w_stash is not None:
+            new_state["w_stash"] = w_stash
         if new_pred is not None:
             new_state["pred"] = new_pred
         return new_state, {"loss": loss, "loss_valid": valid_head}
@@ -486,24 +502,6 @@ def _mpmd_mesh(mesh, n_devices: int):
             f"mpmd runs pure pipeline parallelism; non-pipe mesh axes "
             f"must have size 1, got {extra}")
     return mesh
-
-
-def _trace_mark(tracer, dep):
-    """Ordered host callback attributing wall time to the just-computed
-    event (``repro.obs.trace.PipelineTracer._mark``).
-
-    The callback token carries a data dependence on the event's output,
-    so the mark cannot be scheduled before the compute it brackets; with
-    ``ordered=True`` the callbacks fire in program order — which is the
-    IR's timeline order, so the tracer indexes events by arrival.  Only
-    reached when a tracer is installed: the tracer-less trace/jaxpr is
-    byte-identical to the uninstrumented interpreter.
-    """
-    from jax.experimental import io_callback
-
-    leaf = jax.tree.leaves(dep)[0]
-    tok = jnp.ravel(leaf)[0]
-    io_callback(lambda _t: tracer._mark(), None, tok, ordered=True)
 
 
 def _unsupported(combo: str, why: str, use: str) -> NotImplementedError:
@@ -647,7 +645,7 @@ def make_ir_state(model, params, batch_sds, *, plan,
 
 def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
                        gamma: float = 0.9, clip: Optional[float] = None,
-                       backend: str = "scan", tracer=None,
+                       backend: str = "scan",
                        execution: Optional[str] = None, mesh=None,
                        verify: bool = True, **legacy) -> Callable:
     """Schedule-driven step: one call executes one flush round (gpipe /
@@ -683,12 +681,8 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
     Both backends accumulate gradients, losses and the outer tree in
     the same timeline order, so they are bitwise interchangeable.
 
-    ``tracer`` (a ``repro.obs.trace.PipelineTracer``) instruments the
-    round: the unrolled body wraps every event in a ``jax.named_scope``
-    and both bodies end each event with an ordered host-timestamp
-    callback (``_trace_mark``), which the tracer turns into per-(device,
-    event) spans.  ``tracer=None`` (the default) adds nothing to the
-    trace — the step stays byte-identical to the untraced interpreter.
+    Both bodies name their work with the phases of
+    :mod:`repro.obs.phases` (metadata only).
 
     ``execution`` selects the execution model: ``"spmd"`` (default) runs the
     round as one replicated program (stage weights visible everywhere,
@@ -731,7 +725,7 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
                 "execution='spmd' (runs hybrid models with every "
                 "schedule)")
         return _make_mpmd_step(model, plan=plan, mode=mode, lr=lr,
-                               gamma=gamma, tracer=tracer, mesh=mesh)
+                               gamma=gamma, mesh=mesh)
     sizes = _ir_plan_check(model, plan)
     del sizes
     prog = _round_program(plan)
@@ -773,8 +767,9 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
             if key not in cache:
                 w = base_p["stages"][q]
                 if mode == "spectrain" and s > 0:
-                    w = st.predict_weights(w, base_m["stages"][q], lr,
-                                           float(s))
+                    with phases.scope("predict"), phases.stage(q):
+                        w = st.predict_weights(w, base_m["stages"][q], lr,
+                                               float(s))
                 cache[key] = w
             return cache[key]
 
@@ -783,7 +778,9 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
             if key not in cache:
                 w = base_p["outer"]
                 if mode == "spectrain" and s > 0:
-                    w = st.predict_weights(w, base_m["outer"], lr, float(s))
+                    with phases.scope("predict"):
+                        w = st.predict_weights(w, base_m["outer"], lr,
+                                               float(s))
                 cache[key] = w
             return cache[key]
 
@@ -805,43 +802,37 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
                 return g if a is None else jax.tree.map(jnp.add, a, g)
 
             for kind, m, q, s in prog:
-                scope = (jax.named_scope(f"{kind}/m{m}/q{q}/s{s}")
-                         if tracer is not None else contextlib.nullcontext())
-                with scope:
-                    if kind == "fwd":
+                if kind == "fwd":
+                    with phases.scope("forward"), phases.stage(q):
                         x = model.embed(outer_w(s), mb(m)) if q == 0 \
                             else outs.pop((m, q - 1))
                         acts[(m, q)] = x
                         out, _aux = stage_fn(chunk_w(q, s), x)
                         outs[(m, q)] = out
-                        dep = out
+                    continue
+                if q == C - 1:
+                    with phases.scope("head"):
+                        tgt = mb(m)["targets"]
+                        loss_m, head_vjp = jax.vjp(
+                            lambda o, xl: model.head_loss(o, xl, tgt),
+                            outer_w(s), outs.pop((m, q)))
+                        go_head, cot = head_vjp(jnp.ones((), loss_m.dtype))
+                        g_out_h = acc(g_out_h, go_head)
+                        losses.append(loss_m)
+                else:
+                    cot = cots.pop((m, q + 1))
+                with phases.scope("backward"), phases.stage(q):
+                    _, vjp_q = jax.vjp(stage_fn, chunk_w(q, s),
+                                       acts.pop((m, q)))
+                    gw, gx = vjp_q((cot, jnp.ones((), jnp.float32)))
+                    g_chunks[q] = acc(g_chunks[q], gw)
+                    if q == 0:
+                        _, evjp = jax.vjp(
+                            lambda o: model.embed(o, mb(m)), outer_w(s))
+                        (go_embed,) = evjp(gx)
+                        g_out_e = acc(g_out_e, go_embed)
                     else:
-                        if q == C - 1:
-                            tgt = mb(m)["targets"]
-                            loss_m, head_vjp = jax.vjp(
-                                lambda o, xl: model.head_loss(o, xl, tgt),
-                                outer_w(s), outs.pop((m, q)))
-                            go_head, cot = head_vjp(
-                                jnp.ones((), loss_m.dtype))
-                            g_out_h = acc(g_out_h, go_head)
-                            losses.append(loss_m)
-                        else:
-                            cot = cots.pop((m, q + 1))
-                        _, vjp_q = jax.vjp(stage_fn, chunk_w(q, s),
-                                           acts.pop((m, q)))
-                        gw, gx = vjp_q((cot, jnp.ones((), jnp.float32)))
-                        g_chunks[q] = acc(g_chunks[q], gw)
-                        if q == 0:
-                            _, evjp = jax.vjp(
-                                lambda o: model.embed(o, mb(m)),
-                                outer_w(s))
-                            (go_embed,) = evjp(gx)
-                            g_out_e = acc(g_out_e, go_embed)
-                        else:
-                            cots[(m, q)] = gx
-                        dep = gx
-                if tracer is not None:
-                    _trace_mark(tracer, dep)
+                        cots[(m, q)] = gx
             if acts or outs or cots:
                 raise ValueError(
                     f"{plan.schedule!r} round program (round size {M}) "
@@ -887,15 +878,20 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
                     P, Q, gs, goh, goe, ls = carry
                     m = row[sir.COL_MB]
                     if q == 0:
-                        x = model.embed(Wo, mb(m))
-                        P = jax.lax.dynamic_update_index_in_dim(
-                            P, x, row[sir.COL_A], 0)
+                        with phases.scope("forward"), phases.stage(q):
+                            x = model.embed(Wo, mb(m))
+                        with phases.scope("transfer"):
+                            P = jax.lax.dynamic_update_index_in_dim(
+                                P, x, row[sir.COL_A], 0)
                     else:
-                        x = jax.lax.dynamic_index_in_dim(
-                            P, row[sir.COL_A], 0, keepdims=False)
-                    out, _aux = stage_fn(W, x)
-                    P = jax.lax.dynamic_update_index_in_dim(
-                        P, out, row[sir.COL_B], 0)
+                        with phases.scope("forward"), phases.stage(q):
+                            x = jax.lax.dynamic_index_in_dim(
+                                P, row[sir.COL_A], 0, keepdims=False)
+                    with phases.scope("forward"), phases.stage(q):
+                        out, _aux = stage_fn(W, x)
+                    with phases.scope("transfer"):
+                        P = jax.lax.dynamic_update_index_in_dim(
+                            P, out, row[sir.COL_B], 0)
                     return (P, Q, gs, goh, goe, ls)
                 return br
 
@@ -906,36 +902,41 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
                     P, Q, gs, goh, goe, ls = carry
                     first_g = row[sir.COL_FIRST_G] > 0
                     m = row[sir.COL_MB]
-                    x = jax.lax.dynamic_index_in_dim(
-                        P, row[sir.COL_A], 0, keepdims=False)
+                    with phases.scope("backward"), phases.stage(q):
+                        x = jax.lax.dynamic_index_in_dim(
+                            P, row[sir.COL_A], 0, keepdims=False)
                     if q == C - 1:
-                        out = jax.lax.dynamic_index_in_dim(
-                            P, row[sir.COL_B], 0, keepdims=False)
-                        tgt = mb(m)["targets"]
-                        loss_m, head_vjp = jax.vjp(
-                            lambda o, xl: model.head_loss(o, xl, tgt),
-                            Wo, out)
-                        go_head, cot = head_vjp(jnp.ones((), loss_m.dtype))
-                        goh = first_or_add(goh, go_head,
-                                           row[sir.COL_FIRST_O] > 0)
-                        ls = ls + loss_m
-                    else:
-                        cot = jax.lax.dynamic_index_in_dim(
-                            Q, row[sir.COL_B], 0, keepdims=False)
-                    _, vjp_q = jax.vjp(stage_fn, W, x)
-                    gw, gx = vjp_q((cot, jnp.ones((), jnp.float32)))
-                    gs = tuple(
-                        first_or_add(t, gw, first_g) if i == q else t
-                        for i, t in enumerate(gs))
-                    if q == 0:
-                        _, evjp = jax.vjp(lambda o: model.embed(o, mb(m)),
-                                          Wo)
-                        (go_embed,) = evjp(gx)
-                        goe = first_or_add(goe, go_embed,
-                                           row[sir.COL_FIRST_E] > 0)
-                    else:
-                        Q = jax.lax.dynamic_update_index_in_dim(
-                            Q, gx, row[sir.COL_C], 0)
+                        with phases.scope("head"):
+                            out = jax.lax.dynamic_index_in_dim(
+                                P, row[sir.COL_B], 0, keepdims=False)
+                            tgt = mb(m)["targets"]
+                            loss_m, head_vjp = jax.vjp(
+                                lambda o, xl: model.head_loss(o, xl, tgt),
+                                Wo, out)
+                            go_head, cot = head_vjp(
+                                jnp.ones((), loss_m.dtype))
+                            goh = first_or_add(goh, go_head,
+                                               row[sir.COL_FIRST_O] > 0)
+                            ls = ls + loss_m
+                    with phases.scope("backward"), phases.stage(q):
+                        if q != C - 1:
+                            cot = jax.lax.dynamic_index_in_dim(
+                                Q, row[sir.COL_B], 0, keepdims=False)
+                        _, vjp_q = jax.vjp(stage_fn, W, x)
+                        gw, gx = vjp_q((cot, jnp.ones((), jnp.float32)))
+                        gs = tuple(
+                            first_or_add(t, gw, first_g) if i == q else t
+                            for i, t in enumerate(gs))
+                        if q == 0:
+                            _, evjp = jax.vjp(
+                                lambda o: model.embed(o, mb(m)), Wo)
+                            (go_embed,) = evjp(gx)
+                            goe = first_or_add(goe, go_embed,
+                                               row[sir.COL_FIRST_E] > 0)
+                    if q != 0:
+                        with phases.scope("transfer"):
+                            Q = jax.lax.dynamic_update_index_in_dim(
+                                Q, gx, row[sir.COL_C], 0)
                     return (P, Q, gs, goh, goe, ls)
                 return br
 
@@ -946,14 +947,6 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
             def body(carry, row):
                 carry = jax.lax.switch(row[sir.COL_BRANCH], branches,
                                        carry, row)
-                if tracer is not None:
-                    # token touches both pools and the loss accumulator
-                    # so the mark trails this row's writes
-                    P, Q, _gs, _goh, _goe, ls = carry
-                    _trace_mark(
-                        tracer,
-                        ls + (P.ravel()[0] + Q.ravel()[0]).astype(ls.dtype)
-                        * 0)
                 return carry, None
 
             carry0 = (
@@ -972,12 +965,13 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
 
         g_outer, g_chunks, loss = (scan_round if backend == "scan"
                                    else unrolled_round)()
-        grads = {"outer": g_outer, "stages": tuple(g_chunks)}
-        grads = jax.tree.map(lambda g: g / M, grads)
-        if clip:
-            grads, _ = sgd.clip_by_global_norm(grads, clip)
-        new_params, new_mom = sgd.update(
-            params, sgd.MomentumState(mom), grads, lr=lr, gamma=gamma)
+        with phases.scope("update"):
+            grads = {"outer": g_outer, "stages": tuple(g_chunks)}
+            grads = jax.tree.map(lambda g: g / M, grads)
+            if clip:
+                grads, _ = sgd.clip_by_global_norm(grads, clip)
+            new_params, new_mom = sgd.update(
+                params, sgd.MomentumState(mom), grads, lr=lr, gamma=gamma)
         new_state = {
             **state,
             "params": new_params, "momentum": new_mom.v,
@@ -996,7 +990,7 @@ def make_ir_train_step(model, *, plan, mode: str = "spectrain", lr: float,
 # and cotangents crossing the stage cuts via ppermute ring transfers
 # ===========================================================================
 
-def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
+def _make_mpmd_step(model, *, plan, mode, lr, gamma, mesh):
     """True MPMD round body: one ``shard_map`` over the ``pipe`` axis
     runs each device's tick stream (:meth:`PipelinePlan.device_streams`)
     against its *local* packed weight shard.
@@ -1023,22 +1017,12 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
     itself is elementwise on the packed layout (padding rows stay
     exactly zero), so unpacking the new state reproduces the SPMD state
     leaves byte-for-byte.
-
-    With a ``tracer`` the tick loop is unrolled into one *individually
-    jitted* shard_map call per tick, executed eagerly with a blocking
-    host mark between calls (``io_callback`` is not safe inside
-    shard_map, and an ordered callback's token breaks XLA sharding
-    propagation for explicitly-sharded entry parameters) — so the
-    traced step must NOT be wrapped in an outer ``jax.jit``, and
-    attribution is tick-granular: install the groups from
-    ``obs.trace.device_stream_tick_groups`` on the tracer.
     """
     from jax.sharding import PartitionSpec as P
 
     sizes = _ir_plan_check(model, plan)
     streams = plan.device_streams()
     C, M, S = plan.n_chunks, plan.round_microbatches, plan.n_devices
-    T = streams.rows.shape[0]
     two_buf = max(plan.w_stash_depth) > 1
     mesh = _mpmd_mesh(mesh, S)
     d_head = (C - 1) % S
@@ -1076,10 +1060,11 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
         outer_rd = {}
         for s in lags:
             if mode == "spectrain" and s > 0:
-                stage_rd[s] = st.predict_weights(
-                    base_p["stages"], base_m["stages"], lr, float(s))
-                outer_rd[s] = st.predict_weights(
-                    base_p["outer"], base_m["outer"], lr, float(s))
+                with phases.scope("predict"):
+                    stage_rd[s] = st.predict_weights(
+                        base_p["stages"], base_m["stages"], lr, float(s))
+                    outer_rd[s] = st.predict_weights(
+                        base_p["outer"], base_m["outer"], lr, float(s))
             else:
                 stage_rd[s] = base_p["stages"]
                 outer_rd[s] = base_p["outer"]
@@ -1092,13 +1077,14 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
         head+embed order as the SPMD bodies (a psum would add identity
         elements and flip -0.0 bits) — then apply the SGD update."""
         params, mom = state["params"], state["momentum"]
-        go = jax.tree.map(lambda h, e: h[d_head] + e[0], go_g[i_head],
-                          go_g[i_embed])
         loss = ls_g[d_head] / M
-        grads = {"outer": go, "stages": gs_g}
-        grads = jax.tree.map(lambda g: g / M, grads)
-        new_params, new_mom = sgd.update(
-            params, sgd.MomentumState(mom), grads, lr=lr, gamma=gamma)
+        with phases.scope("update"):
+            go = jax.tree.map(lambda h, e: h[d_head] + e[0], go_g[i_head],
+                              go_g[i_embed])
+            grads = {"outer": go, "stages": gs_g}
+            grads = jax.tree.map(lambda g: g / M, grads)
+            new_params, new_mom = sgd.update(
+                params, sgd.MomentumState(mom), grads, lr=lr, gamma=gamma)
         new_state = {
             **state,
             "params": new_params, "momentum": new_mom.v,
@@ -1108,8 +1094,6 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
             new_state["stash"] = {"params": params, "momentum": mom}
         return new_state, {"loss": loss,
                            "loss_valid": jnp.ones((), jnp.float32)}
-
-    _jits: dict = {}   # traced path: cached pre / per-tick / post jits
 
     def step(state: Dict[str, Any], batch):
         B = jax.tree.leaves(batch)[0].shape[0]
@@ -1175,19 +1159,24 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                     V, Ct, gs, go, ls = carry
                     m = row[sir.DCOL_MB]
                     if q == 0:
-                        x = model.embed(ord_l[s], mb(m))
-                        V = jax.lax.dynamic_update_index_in_dim(
-                            V, x, row[sir.DCOL_A], 0)
+                        with phases.scope("forward"), phases.stage(q):
+                            x = model.embed(ord_l[s], mb(m))
+                        with phases.scope("transfer"):
+                            V = jax.lax.dynamic_update_index_in_dim(
+                                V, x, row[sir.DCOL_A], 0)
                     else:
-                        x = jax.lax.dynamic_index_in_dim(
-                            V, row[sir.DCOL_A], 0, keepdims=False)
-                    out, _aux = stage_fn(chunk_of(s, q), x)
-                    if q == C - 1:
-                        V = jax.lax.dynamic_update_index_in_dim(
-                            V, out, row[sir.DCOL_B], 0)
-                        sf = zeros_x()
-                    else:
-                        sf = out
+                        with phases.scope("forward"), phases.stage(q):
+                            x = jax.lax.dynamic_index_in_dim(
+                                V, row[sir.DCOL_A], 0, keepdims=False)
+                    with phases.scope("forward"), phases.stage(q):
+                        out, _aux = stage_fn(chunk_of(s, q), x)
+                    with phases.scope("transfer"):
+                        if q == C - 1:
+                            V = jax.lax.dynamic_update_index_in_dim(
+                                V, out, row[sir.DCOL_B], 0)
+                            sf = zeros_x()
+                        else:
+                            sf = out
                     return (V, Ct, gs, go, ls), sf, zeros_x()
                 return br
 
@@ -1195,34 +1184,36 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                 def br(carry, row):
                     V, Ct, gs, go, ls = carry
                     m = row[sir.DCOL_MB]
-                    x = jax.lax.dynamic_index_in_dim(
-                        V, row[sir.DCOL_A], 0, keepdims=False)
+                    with phases.scope("backward"), phases.stage(q):
+                        x = jax.lax.dynamic_index_in_dim(
+                            V, row[sir.DCOL_A], 0, keepdims=False)
                     if q == C - 1:
-                        out = jax.lax.dynamic_index_in_dim(
-                            V, row[sir.DCOL_B], 0, keepdims=False)
-                        tgt = mb(m)["targets"]
-                        loss_m, head_vjp = jax.vjp(
-                            lambda o, xl: model.head_loss(o, xl, tgt),
-                            ord_l[s], out)
-                        go_head, cot = head_vjp(jnp.ones((), loss_m.dtype))
-                        go = outer_acc(go, i_head, go_head,
-                                       row[sir.DCOL_FIRST_O] > 0)
-                        ls = ls + loss_m
-                    else:
-                        cot = jax.lax.dynamic_index_in_dim(
-                            Ct, row[sir.DCOL_C], 0, keepdims=False)
-                    _, vjp_q = jax.vjp(stage_fn, chunk_of(s, q), x)
-                    gw, gx = vjp_q((cot, jnp.ones((), jnp.float32)))
-                    gs = gs_acc(gs, gw, q, row[sir.DCOL_FIRST_G] > 0)
-                    if q == 0:
-                        _, evjp = jax.vjp(lambda o: model.embed(o, mb(m)),
-                                          ord_l[s])
-                        (go_embed,) = evjp(gx)
-                        go = outer_acc(go, i_embed, go_embed,
-                                       row[sir.DCOL_FIRST_E] > 0)
-                        sb = zeros_x()
-                    else:
-                        sb = gx
+                        with phases.scope("head"):
+                            out = jax.lax.dynamic_index_in_dim(
+                                V, row[sir.DCOL_B], 0, keepdims=False)
+                            tgt = mb(m)["targets"]
+                            loss_m, head_vjp = jax.vjp(
+                                lambda o, xl: model.head_loss(o, xl, tgt),
+                                ord_l[s], out)
+                            go_head, cot = head_vjp(
+                                jnp.ones((), loss_m.dtype))
+                            go = outer_acc(go, i_head, go_head,
+                                           row[sir.DCOL_FIRST_O] > 0)
+                            ls = ls + loss_m
+                    with phases.scope("backward"), phases.stage(q):
+                        if q != C - 1:
+                            cot = jax.lax.dynamic_index_in_dim(
+                                Ct, row[sir.DCOL_C], 0, keepdims=False)
+                        _, vjp_q = jax.vjp(stage_fn, chunk_of(s, q), x)
+                        gw, gx = vjp_q((cot, jnp.ones((), jnp.float32)))
+                        gs = gs_acc(gs, gw, q, row[sir.DCOL_FIRST_G] > 0)
+                        if q == 0:
+                            _, evjp = jax.vjp(
+                                lambda o: model.embed(o, mb(m)), ord_l[s])
+                            (go_embed,) = evjp(gx)
+                            go = outer_acc(go, i_embed, go_embed,
+                                           row[sir.DCOL_FIRST_E] > 0)
+                    sb = zeros_x() if q == 0 else gx
                     return (V, Ct, gs, go, ls), zeros_x(), sb
                 return br
 
@@ -1237,17 +1228,18 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
                 # both rings run every tick (idle devices carry the
                 # NOP's garbage payload into a trash slot) so the
                 # program stays SPMD while the execution is MPMD
-                rf = jax.lax.ppermute(sf, "pipe", fwd_perm) if S > 1 \
-                    else sf
-                rb = jax.lax.ppermute(sb, "pipe", bwd_perm) if S > 1 \
-                    else sb
-                V, Ct, gs, go, ls = carry
-                V = jax.lax.dynamic_update_index_in_dim(
-                    V, rf, jnp.where(row[sir.DCOL_RECV_F] >= 0,
-                                     row[sir.DCOL_RECV_F], nv), 0)
-                Ct = jax.lax.dynamic_update_index_in_dim(
-                    Ct, rb, jnp.where(row[sir.DCOL_RECV_B] >= 0,
-                                      row[sir.DCOL_RECV_B], nc), 0)
+                with phases.scope("transfer"):
+                    rf = jax.lax.ppermute(sf, "pipe", fwd_perm) if S > 1 \
+                        else sf
+                    rb = jax.lax.ppermute(sb, "pipe", bwd_perm) if S > 1 \
+                        else sb
+                    V, Ct, gs, go, ls = carry
+                    V = jax.lax.dynamic_update_index_in_dim(
+                        V, rf, jnp.where(row[sir.DCOL_RECV_F] >= 0,
+                                         row[sir.DCOL_RECV_F], nv), 0)
+                    Ct = jax.lax.dynamic_update_index_in_dim(
+                        Ct, rb, jnp.where(row[sir.DCOL_RECV_B] >= 0,
+                                          row[sir.DCOL_RECV_B], nc), 0)
                 return (V, Ct, gs, go, ls)
             return tick
 
@@ -1263,79 +1255,24 @@ def _make_mpmd_step(model, *, plan, mode, lr, gamma, tracer, mesh):
 
         expand = lambda t: jax.tree.map(lambda x: x[None], t)
 
-        if tracer is None:
-            mbs, stage_rd, outer_rd = _pre(state, batch)
+        mbs, stage_rd, outer_rd = _pre(state, batch)
 
-            def round_body(rows_l, mbs_l, srd_l, ord_l):
-                tick = make_tick(mbs_l, srd_l, ord_l)
+        def round_body(rows_l, mbs_l, srd_l, ord_l):
+            tick = make_tick(mbs_l, srd_l, ord_l)
 
-                def body(carry, row):
-                    return tick(carry, row[0]), None
+            def body(carry, row):
+                return tick(carry, row[0]), None
 
-                (_V, _Ct, gs, go, ls), _ = jax.lax.scan(
-                    body, local_carry0(srd_l, ord_l), rows_l)
-                return gs, expand(go), ls[None]
+            (_V, _Ct, gs, go, ls), _ = jax.lax.scan(
+                body, local_carry0(srd_l, ord_l), rows_l)
+            return gs, expand(go), ls[None]
 
-            run = jax.shard_map(
-                round_body, mesh=mesh,
-                in_specs=(P(None, "pipe", None), P(), P(None, "pipe"),
-                          P()),
-                out_specs=(P(None, "pipe"), P("pipe"), P("pipe")),
-                check_vma=False)
-            gs_g, go_g, ls_g = run(rows, mbs, stage_rd, outer_rd)
-            return _post(state, gs_g, go_g, ls_g)
-        else:
-            # tick-unrolled: one jitted shard_map per tick, a blocking
-            # host mark between calls — io_callback is not safe inside
-            # shard_map, and an ordered callback's token breaks XLA
-            # sharding propagation with explicitly-sharded parameters,
-            # so the traced round runs *eagerly* (per-tick jit, cached
-            # after the first call).  Device-local carries cross the
-            # calls as pipe-sharded globals (pools/outer partials gain
-            # a leading [S] axis).
-            if isinstance(jax.tree.leaves(state)[0], jax.core.Tracer):
-                raise ValueError(
-                    "the traced mpmd step measures real per-tick wall "
-                    "time and must not be wrapped in an outer jax.jit "
-                    "— call it eagerly (it jits each tick internally)")
-            if not _jits:
-                def tick_body(row_l, mbs_l, srd_l, ord_l,
-                              V_l, Ct_l, gs, go_l, ls_l):
-                    tick = make_tick(mbs_l, srd_l, ord_l)
-                    carry = (V_l[0], Ct_l[0], gs,
-                             jax.tree.map(lambda x: x[0], go_l), ls_l[0])
-                    V, Ct, gs, go, ls = tick(carry, row_l[0])
-                    return (V[None], Ct[None], gs, expand(go), ls[None])
-
-                _jits["tick"] = jax.jit(jax.shard_map(
-                    tick_body, mesh=mesh,
-                    in_specs=(P("pipe", None), P(), P(None, "pipe"),
-                              P(), P("pipe"), P("pipe"),
-                              P(None, "pipe"), P("pipe"), P("pipe")),
-                    out_specs=(P("pipe"), P("pipe"), P(None, "pipe"),
-                               P("pipe"), P("pipe")),
-                    check_vma=False), donate_argnums=(4, 5, 6, 7, 8))
-                # the prologue and epilogue run under their own jits:
-                # eager op-by-op execution would skip the FMA fusion
-                # XLA applies inside the untraced step's single jit and
-                # break bitwise parity with it
-                _jits["pre"] = jax.jit(_pre)
-                _jits["post"] = jax.jit(_post)
-            mbs, stage_rd, outer_rd = _jits["pre"](state, batch)
-            run = _jits["tick"]
-            Vg = jnp.zeros((S, nv + 1) + x_sd.shape, x_sd.dtype)
-            Cg = jnp.zeros((S, nc + 1) + x_sd.shape, x_sd.dtype)
-            gs_g = jax.tree.map(jnp.zeros_like, stage_rd[lags[0]])
-            big = lambda t: jax.tree.map(
-                lambda x: jnp.zeros((S,) + x.shape, x.dtype), t)
-            go_g = tuple(big(outer_rd[lags[0]]) for _ in range(n_outer))
-            ls_g = jnp.zeros((S,), loss_sd.dtype)
-            for t in range(T):
-                Vg, Cg, gs_g, go_g, ls_g = run(
-                    rows[t], mbs, stage_rd, outer_rd,
-                    Vg, Cg, gs_g, go_g, ls_g)
-                jax.block_until_ready(ls_g)
-                tracer._mark()
-            return _jits["post"](state, gs_g, go_g, ls_g)
+        run = jax.shard_map(
+            round_body, mesh=mesh,
+            in_specs=(P(None, "pipe", None), P(), P(None, "pipe"), P()),
+            out_specs=(P(None, "pipe"), P("pipe"), P("pipe")),
+            check_vma=False)
+        gs_g, go_g, ls_g = run(rows, mbs, stage_rd, outer_rd)
+        return _post(state, gs_g, go_g, ls_g)
 
     return step

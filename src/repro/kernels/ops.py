@@ -6,8 +6,6 @@ exercised with ``interpret=True`` against the ``ref.py`` oracles.
 from __future__ import annotations
 
 import functools
-import time
-from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -16,34 +14,6 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import mamba2_scan as m2
 from repro.kernels import rwkv6_scan as r6
 from repro.kernels import fused_update as fu
-
-
-# ---------------------------------------------------------------------------
-# timing hook: obs.MetricsRegistry.kernel_hook() plugs in here.  When no
-# hook is set (the default, and the whole training hot path — these
-# wrappers only run eagerly on the serve/prefill path) the wrappers are
-# untouched: the timed path synchronizes via block_until_ready, which
-# would serialize dispatch if left on unconditionally.
-
-_timing_hook: Optional[Callable[[str, float], None]] = None
-
-
-def set_timing_hook(hook: Optional[Callable[[str, float], None]]) -> None:
-    """Install (or clear, with ``None``) a ``hook(kernel_name, microseconds)``
-    called after each public kernel wrapper returns."""
-    global _timing_hook
-    _timing_hook = hook
-
-
-def _timed(name: str, fn, *args, **kw):
-    if _timing_hook is None or any(
-            isinstance(a, jax.core.Tracer)
-            for a in jax.tree.leaves((args, kw))):
-        return fn(*args, **kw)      # no hook, or inside a jit trace
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(fn(*args, **kw))
-    _timing_hook(name, (time.perf_counter() - t0) * 1e6)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -115,11 +85,6 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def rwkv6_scan(r, k, v, w, u, S0, *, chunk: int = 32,
                interpret: bool = False):
     """Layout [b, s, h, hd] (model-side) -> kernel layout [b, h, s, hd]."""
-    return _timed("rwkv6_scan", _rwkv6_scan, r, k, v, w, u, S0,
-                  chunk=chunk, interpret=interpret)
-
-
-def _rwkv6_scan(r, k, v, w, u, S0, *, chunk, interpret):
     tr = lambda t: jnp.swapaxes(t, 1, 2)
     y, sT = r6.rwkv6_scan(tr(r), tr(k), tr(v), tr(w), u, S0,
                           chunk=chunk, interpret=interpret)
@@ -130,11 +95,6 @@ def mamba2_scan(x, dt, decay, B, C, S0, *, chunk: int = 32,
                 interpret: bool = False):
     """Model-side layouts: x [b,s,h,p]; dt/decay [b,s,h]; B,C [b,s,g,n]
     (groups broadcast to heads here)."""
-    return _timed("mamba2_scan", _mamba2_scan, x, dt, decay, B, C, S0,
-                  chunk=chunk, interpret=interpret)
-
-
-def _mamba2_scan(x, dt, decay, B, C, S0, *, chunk, interpret):
     h = x.shape[2]
     g = B.shape[2]
     rep = h // g

@@ -94,9 +94,6 @@ def main(argv=None) -> int:
 
     registry = MetricsRegistry(jsonl_path=args.metrics_out or None)
     try:
-        if args.metrics_out:
-            from repro.kernels import ops as kernel_ops
-            kernel_ops.set_timing_hook(registry.kernel_hook())
         cfg = smoke_config(get_config(args.arch))
         kw = {}
         if args.layers:

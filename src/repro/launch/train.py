@@ -35,6 +35,7 @@ other via the flat layer order.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import time
@@ -50,10 +51,7 @@ from repro.core import pipeline_stream, pipeline_sync
 from repro.data import KINDS, DataConfig, SyntheticLM
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
-from repro.obs import (MetricsRegistry, PipelineTracer,
-                       device_stream_tick_groups, drift_report,
-                       format_drift, format_step, probe_stage_costs,
-                       write_trace)
+from repro.obs import MetricsRegistry, format_step
 from repro.planner import check_against_closed_forms, plan as make_plan
 from repro.runtime import checkpoint as ckpt
 
@@ -116,10 +114,11 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--json", action="store_true",
                     help="emit one JSON line per logged step")
-    ap.add_argument("--trace", default="",
-                    help="write a Perfetto/Chrome trace JSON (per-device "
-                         "measured + IR-predicted lanes) to this path and "
-                         "print the predicted-vs-measured drift report")
+    ap.add_argument("--trace", default="", metavar="DIR",
+                    help="profile every step after the first (compiling) "
+                         "one with jax.profiler into DIR: an .xplane.pb "
+                         "for XProf and a Perfetto trace, each operation "
+                         "named by its step phase (repro.obs.phases)")
     ap.add_argument("--metrics-out", default="", dest="metrics_out",
                     help="append structured JSONL telemetry (step records, "
                          "heartbeat/restate events, summary) to this path")
@@ -167,11 +166,6 @@ def setup(args) -> Setup:
         raise SystemExit(
             f"--mode sync runs the fill/drain pipeline and cannot honor "
             f"--schedule {args.schedule}; drop one of the two flags")
-    if args.trace and args.mode == "sync":
-        raise SystemExit("--trace instruments the streaming/IR runtimes; "
-                         "--mode sync is not traceable")
-    if args.trace and args.pipe < 2:
-        raise SystemExit("--trace needs a real pipeline (--pipe >= 2)")
     if args.virtual_stages > 1 and args.schedule != "interleaved":
         raise SystemExit(
             f"--virtual-stages {args.virtual_stages} requires "
@@ -242,10 +236,6 @@ def main(argv=None) -> int:
     key = jax.random.PRNGKey(args.seed)
 
     registry = MetricsRegistry(jsonl_path=args.metrics_out or None)
-    if args.metrics_out:
-        from repro.kernels import ops as kernel_ops
-        kernel_ops.set_timing_hook(registry.kernel_hook())
-    tracer = PipelineTracer(pplan) if args.trace else None
 
     if args.mode == "sync":
         state = pipeline_sync.init_state(model, key)
@@ -254,25 +244,10 @@ def main(argv=None) -> int:
             num_microbatches=cfg.mesh_plan.num_microbatches,
             clip=args.clip or None)
         step_fn = jax.jit(step_fn, donate_argnums=0)
-        if tracer is not None:
-            step_fn = tracer.wrap_step(step_fn)
     else:
-        # the Runtime facade owns jit/donation (and the traced-mpmd
-        # per-tick exception) for both schedule families
-        rt = Runtime(pplan, model, rc, tracer=tracer)
+        # the Runtime facade owns jit/donation for both schedule families
+        rt = Runtime(pplan, model, rc)
         state = rt.init(key, batch_sds)
-        if tracer is not None and rc.execution == "mpmd":
-            # the mpmd round runs T device-stream ticks, not one host
-            # mark per compute event — map tick marks back onto the
-            # per-event timeline
-            tracer.set_tick_groups(device_stream_tick_groups(pplan))
-        if tracer is not None and schedule == "stream":
-            # the fused tick step is not separable per stage -- probe
-            # each stage's cost in isolation (PipeDream-style) for the
-            # per-device attribution in the trace and drift report
-            tracer.set_probed(probe_stage_costs(
-                model, state["params"]["stages"],
-                mb=max(1, args.batch // args.ticks), seq=args.seq))
         step_fn = rt.train_step
 
     start = 0
@@ -288,15 +263,26 @@ def main(argv=None) -> int:
     print(f"# arch={cfg.name} params={n_params:,} mode={args.mode} "
           f"pipe={model.n_stages} opt_floor={data.optimal_loss():.4f}")
 
+    # tok/s counts from the end of the first step, which compiles
     t0 = time.time()
     tokens = 0
     bg_save = None
     interrupted = False
+    profile = contextlib.ExitStack()
     try:
         for s in range(start, args.steps):
+            if args.trace and s == start + 1:
+                profile.enter_context(jax.profiler.trace(
+                    args.trace, create_perfetto_trace=True))
             batch = data.batch_at(s)
-            state, metrics = step_fn(state, batch)
-            tokens += args.batch * args.seq
+            with jax.profiler.StepTraceAnnotation("train_step",
+                                                  step_num=s + 1):
+                state, metrics = step_fn(state, batch)
+            if s == start:
+                jax.block_until_ready(metrics["loss"])
+                t0 = time.time()
+            else:
+                tokens += args.batch * args.seq
             if args.ckpt_dir and (s + 1) % args.save_every == 0:
                 if bg_save is not None:
                     bg_save.join()  # never two writers on the same dir
@@ -313,17 +299,16 @@ def main(argv=None) -> int:
         interrupted = True
         print("# interrupted -- metrics flushed")
     finally:
+        profile.close()
         registry.close()
     if args.ckpt_dir:
         if bg_save is not None:
             bg_save.join()
         if not interrupted:
             ckpt.save(args.ckpt_dir, state, args.steps - 1)
-    if tracer is not None and tracer.n_steps():
-        write_trace(args.trace, tracer)
-        print(f"# trace written to {args.trace} "
-              f"({tracer.n_steps()} steps recorded)")
-        print(format_drift(drift_report(tracer)))
+    if args.trace and args.steps - start > 1:
+        print(f"# profile of steps {start + 2}..{args.steps} written to "
+              f"{args.trace}")
     return 1 if interrupted else 0
 
 

@@ -1,27 +1,13 @@
-"""Observability: pipeline tracing, drift reports, metrics registry.
+"""Observability: named step phases and the metrics registry.
 
-The measurement counterpart of the planner: the schedule IR *predicts* a
-per-device timeline (bubble fraction, staleness, per-stage cost); this
-package *measures* one from the running interpreter and diffs the two —
-the feedback loop that turns the planner from open-loop to closed-loop.
-
-  * :mod:`repro.obs.trace`    — :class:`PipelineTracer`: per-event host
-    timestamps from the IR interpreter backends, per-step wall time for
-    the streaming runtime, and a parallel-timeline reconstruction.
-  * :mod:`repro.obs.perfetto` — Chrome/Perfetto trace-JSON export
-    (measured + predicted lane groups) and a trace-schema validator.
-  * :mod:`repro.obs.drift`    — predicted-vs-measured drift report:
-    realized bubble, per-stage busy/idle shares, staleness histograms,
-    per-stage cost-model relative error.
+  * :mod:`repro.obs.phases`   — the ``jax.named_scope`` phases every
+    training runtime names its step's work with, and the map from a
+    compiled program's instructions to those phases that reads a
+    device trace (``train.py --trace DIR``, ``bench/phases.py``).
   * :mod:`repro.obs.metrics`  — counters / gauges / histograms +
     structured events → JSONL and a summary table; the one code path
     behind ``train.py``'s human and ``--json`` step records.
 """
-from repro.obs.drift import drift_report, format_drift  # noqa: F401
+from repro.obs import phases  # noqa: F401
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry, format_step)
-from repro.obs.perfetto import (trace_events, validate_trace,  # noqa: F401
-                                write_trace)
-from repro.obs.trace import (PipelineTracer, Span,  # noqa: F401
-                             device_stream_tick_groups,
-                             probe_stage_costs, round_event_metas)

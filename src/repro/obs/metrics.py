@@ -114,13 +114,6 @@ class MetricsRegistry:
     def histogram(self, name: str) -> Histogram:
         return self._hists.setdefault(name, Histogram())
 
-    def kernel_hook(self) -> Callable[[str, float], None]:
-        """Timing hook for ``kernels.ops.set_timing_hook``: feeds each
-        (kernel name, microseconds) sample into a histogram."""
-        def hook(name: str, us: float) -> None:
-            self.histogram(f"kernel/{name}_us").observe(us)
-        return hook
-
     # -------------------------------------------------------------- events
     def emit(self, event: str, **fields: Any) -> Dict[str, Any]:
         rec = {"event": event, "t": self.clock(), **fields}

@@ -164,11 +164,6 @@ class TestRuntimeFacade:
         Runtime(p, m, RuntimeConfig(schedule="1f1b"))   # matching: fine
         Runtime(p, m)                                   # None adopts
 
-    def test_tracer_requires_trace_flag(self, ir_setup):
-        m, p, _ = ir_setup
-        with pytest.raises(ValueError, match="trace"):
-            Runtime(p, m, RuntimeConfig(), tracer=object())
-
     def test_workload_dispatch_is_typed(self, ir_setup):
         m, p, _ = ir_setup
         splan = serve_plan(None, n_slots=2, max_prefill=1,

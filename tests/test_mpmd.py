@@ -6,8 +6,8 @@ Three layers of evidence for ``execution="mpmd"`` in
   * **Device streams** — lowering the round event table to per-device
     int32 streams is structurally sound: every device runs T ticks,
     branch ids index the stream's branch set (or the NOP), receive
-    slots index the pools, and the tick grouping used by the tracer
-    covers every compute event exactly once.
+    slots index the pools, and the ticks run every compute event
+    exactly once.
   * **Bit identity** — the shard_map round (stage weights resident
     only on their pipe device, activations/cotangents crossing stage
     cuts via ppermute) is bitwise identical to the SPMD scan backend
@@ -122,14 +122,24 @@ class TestDeviceStreams:
         np.testing.assert_array_equal(a.rows, b.rows)
 
     def test_tick_groups_cover_events(self):
-        from repro.obs import device_stream_tick_groups, round_event_metas
+        """The ticks of the device streams run every compute event of
+        the round program exactly once, on the device of its chunk."""
         for schedule, S, v in (("1f1b", 2, 1), ("2bw", 3, 1),
                                ("interleaved", 2, 2)):
             p = _mk_plan(schedule, S, v=v, M=2 * S, L=2 * S * v)
-            groups = device_stream_tick_groups(p)
-            assert len(groups) == p.device_streams().rows.shape[0]
-            flat = sorted(i for g in groups for i in g)
-            assert flat == list(range(len(round_event_metas(p))))
+            ds = p.device_streams()
+            nop = len(ds.branches)
+            ran = []
+            for t in range(ds.rows.shape[0]):
+                for d in range(S):
+                    b = int(ds.rows[t, d, sir.DCOL_BRANCH])
+                    if b == nop:
+                        continue
+                    kind, q, s = ds.branches[b]
+                    assert q % S == d
+                    ran.append((kind, int(ds.rows[t, d, sir.DCOL_MB]),
+                                q, s))
+            assert sorted(ran) == sorted(p.round_program())
 
 
 # ===========================================================================
@@ -179,39 +189,6 @@ class TestMpmdBitIdentity:
         for a, b in zip(ls, lm):
             assert a.tobytes() == b.tobytes(), (a, b)
         _assert_states_match(sm, ss)
-
-    def test_traced_step_matches_and_guards(self):
-        """The per-tick traced variant (tracer set) trains bitwise the
-        same as the untraced mpmd step, records every round, and
-        refuses an outer jit."""
-        from repro.obs import PipelineTracer, device_stream_tick_groups
-        p = _mk_plan("1f1b", 1, M=4, L=4, partitioner="uniform")
-        cfg = tiny_cfg("granite-8b", n_layers=4, pipe=1)
-        m = Model(cfg)
-        params = m.init(jax.random.PRNGKey(0))
-        batch = lm_batch(jax.random.PRNGKey(1), cfg,
-                         batch=2 * p.round_microbatches, seq=8)
-        tracer = PipelineTracer(p)
-        tracer.set_tick_groups(device_stream_tick_groups(p))
-        state = pipeline_stream.make_ir_state(m, params, None, plan=p,
-                                              mode="spectrain",
-                                              execution="mpmd")
-        step = tracer.wrap_step(pipeline_stream.make_ir_train_step(
-            m, plan=p, mode="spectrain", lr=0.05, execution="mpmd",
-            tracer=tracer))
-        losses = []
-        for _ in range(2):
-            state, met = step(state, batch)
-            losses.append(np.asarray(met["loss"]))
-        assert tracer.dropped_rounds == 0 and len(tracer.rounds) == 2
-        lm, _sm = _run("mpmd", p, "spectrain")
-        for a, b in zip(losses, lm):
-            assert a.tobytes() == b.tobytes(), (a, b)
-        bad = jax.jit(pipeline_stream.make_ir_train_step(
-            m, plan=p, mode="spectrain", lr=0.05, execution="mpmd",
-            tracer=tracer))
-        with pytest.raises(ValueError, match="outer jax.jit"):
-            bad(state, batch)
 
 
 # ===========================================================================

@@ -1,25 +1,10 @@
-"""Observability subsystem: tracer, Perfetto export, drift, metrics.
-
-The golden trace test runs the IR interpreter under a fake clock that
-advances exactly one second per reading, so every measured event
-duration is exactly 1.0 — the reconstruction must then reproduce the
-IR's unit-cost timeline *exactly*: per-device event order equal to the
-event table's, and realized bubble fraction equal to the plan's
-closed-form ``bubble_frac``.
-"""
+"""The metrics registry: instruments, the JSONL stream and the step
+record ``train.py`` prints."""
 import json
 
-import jax
 import pytest
 
-from conftest import tiny_cfg
-from repro.core import pipeline_stream
-from repro.models import Model
-from repro.obs import (MetricsRegistry, PipelineTracer, drift_report,
-                       format_drift, format_step, probe_stage_costs,
-                       round_event_metas, trace_events, validate_trace,
-                       write_trace)
-from repro.planner import plan, synthetic_profile
+from repro.obs import MetricsRegistry, format_step
 
 
 class FakeClock:
@@ -31,206 +16,6 @@ class FakeClock:
     def __call__(self):
         self.t += 1.0
         return self.t
-
-
-def _ir_setup(schedule="1f1b", M=4, S=2, n_layers=4, seq=16):
-    cfg = tiny_cfg("granite-8b", n_layers=n_layers, pipe=S)
-    model = Model(cfg)
-    p = plan(profile=synthetic_profile([1.0] * cfg.n_layers),
-             n_stages=S, schedule=schedule, n_microbatches=M)
-    k = jax.random.PRNGKey(1)
-    batch = {
-        "tokens": jax.random.randint(k, (M, seq), 0, cfg.vocab_size),
-        "targets": jax.random.randint(k, (M, seq), 0, cfg.vocab_size),
-    }
-    return model, p, batch
-
-
-def _run_traced(model, p, batch, backend, steps=3):
-    tracer = PipelineTracer(p, clock=FakeClock())
-    params = model.init(jax.random.PRNGKey(0))
-    state = pipeline_stream.make_ir_state(model, params, None, plan=p)
-    step = tracer.wrap_step(jax.jit(pipeline_stream.make_ir_train_step(
-        model, plan=p, mode="spectrain", lr=0.05, backend=backend,
-        tracer=tracer), donate_argnums=0))
-    metrics = None
-    for _ in range(steps):
-        state, metrics = step(state, batch)
-    return tracer, metrics
-
-
-class TestRoundEventMetas:
-    @pytest.mark.parametrize("schedule", ["gpipe", "1f1b", "2bw"])
-    def test_matches_round_program(self, schedule):
-        _, p, _ = _ir_setup(schedule=schedule)
-        metas = round_event_metas(p)
-        prog = p.round_program()
-        assert len(metas) == len(prog)
-        for m, (kind, mb, q, s) in zip(metas, prog):
-            assert (m["kind"], m["mb"], m["chunk"], m["wv"]) == \
-                (kind, mb, q, s)
-        # ticks are non-decreasing nowhere required, but devices valid
-        assert all(0 <= m["device"] < p.n_devices for m in metas)
-
-    def test_interleaved_devices_fold_chunks(self):
-        cfg = tiny_cfg("granite-8b", n_layers=4, pipe=2)
-        model = Model(cfg)
-        p = plan(profile=synthetic_profile([1.0] * 4), n_stages=2,
-                 schedule="interleaved", virtual_stages=2,
-                 n_microbatches=4)
-        metas = round_event_metas(p)
-        assert {m["device"] for m in metas} == set(range(p.n_devices))
-        assert {m["chunk"] for m in metas} == set(range(p.n_chunks))
-        del model
-
-
-class TestGoldenTrace:
-    @pytest.mark.parametrize("backend", pipeline_stream.IR_BACKENDS)
-    def test_order_and_bubble_exact(self, backend):
-        """Uniform S=2 1f1b: measured per-device event order equals the
-        IR event table's, and the fake-clock bubble equals the plan's."""
-        model, p, batch = _ir_setup(schedule="1f1b", M=4, S=2)
-        tracer, _ = _run_traced(model, p, batch, backend)
-        assert tracer.n_steps() == 3
-        assert len(tracer.rounds) == 3
-        assert tracer.dropped_rounds == 0
-        # every measured duration is exactly one fake-clock second
-        assert all(d == 1.0 for r in tracer.rounds for d in r)
-
-        spans, makespan = tracer.measured_timeline()
-        # per-device order of measured spans == event-table order
-        metas = tracer.metas
-        for d in range(p.n_devices):
-            measured = [(s.args["op"], s.args["mb"], s.args["chunk"])
-                        for s in sorted((s for s in spans if s.device == d),
-                                        key=lambda s: s.t0)]
-            predicted = [(m["kind"], m["mb"], m["chunk"])
-                         for m in metas if m["device"] == d]
-            assert measured == predicted
-        # unit durations reproduce the IR's unit-cost bubble exactly
-        from repro.obs.trace import timeline_stats
-        stats = timeline_stats(spans, makespan, p.n_devices)
-        assert stats["bubble_frac"] == pytest.approx(p.bubble_frac)
-
-    def test_scan_unrolled_same_order(self):
-        model, p, batch = _ir_setup(schedule="1f1b", M=4, S=2)
-        orders = []
-        for backend in pipeline_stream.IR_BACKENDS:
-            tracer, _ = _run_traced(model, p, batch, backend, steps=2)
-            spans, _ = tracer.measured_timeline()
-            orders.append([(s.device, s.name) for s in spans])
-        assert orders[0] == orders[1]
-
-    @pytest.mark.parametrize("backend", pipeline_stream.IR_BACKENDS)
-    def test_tracing_does_not_change_numerics(self, backend):
-        """The tracer's callbacks are observation-only: traced and
-        untraced runs produce bit-identical losses."""
-        model, p, batch = _ir_setup(schedule="1f1b", M=4, S=2)
-        _, traced = _run_traced(model, p, batch, backend, steps=2)
-
-        params = model.init(jax.random.PRNGKey(0))
-        state = pipeline_stream.make_ir_state(model, params, None, plan=p)
-        step = jax.jit(pipeline_stream.make_ir_train_step(
-            model, plan=p, mode="spectrain", lr=0.05, backend=backend),
-            donate_argnums=0)
-        for _ in range(2):
-            state, plain = step(state, batch)
-        assert float(traced["loss"]) == float(plain["loss"])
-
-
-class TestPerfetto:
-    def _tracer(self):
-        model, p, batch = _ir_setup()
-        tracer, _ = _run_traced(model, p, batch, "scan", steps=2)
-        return tracer
-
-    def test_trace_events_valid_and_json(self, tmp_path):
-        tracer = self._tracer()
-        obj = trace_events(tracer)
-        assert validate_trace(obj) == []
-        json.dumps(obj)     # must be JSON-serializable as-is
-        # both lane groups present with one thread lane per device
-        xs = [e for e in obj["traceEvents"] if e["ph"] == "X"]
-        assert {e["pid"] for e in xs} == {0, 1}
-        assert {e["tid"] for e in xs if e["pid"] == 0} == \
-            set(range(tracer.plan.n_devices))
-        path = tmp_path / "trace.json"
-        write_trace(str(path), tracer)
-        assert validate_trace(json.load(open(path))) == []
-
-    def test_validate_catches_problems(self):
-        assert validate_trace([]) != []
-        assert validate_trace({}) != []
-        assert validate_trace({"traceEvents": [{"ph": "Z"}]}) != []
-        bad_ts = {"traceEvents": [
-            {"ph": "X", "name": "e", "pid": 0, "tid": 0,
-             "ts": float("nan"), "dur": 1.0},
-            {"ph": "X", "name": "e", "pid": 1, "tid": 0,
-             "ts": 0.0, "dur": 1.0}]}
-        assert any("ts" in p for p in validate_trace(bad_ts))
-        # a trace missing the predicted lane group is invalid
-        only_measured = {"traceEvents": [
-            {"ph": "X", "name": "e", "pid": 0, "tid": 0,
-             "ts": 0.0, "dur": 1.0}]}
-        assert any("predicted" in p for p in validate_trace(only_measured))
-
-
-class TestDrift:
-    def test_report_fields_and_format(self):
-        model, p, batch = _ir_setup(schedule="1f1b", M=4, S=2)
-        tracer, _ = _run_traced(model, p, batch, "scan", steps=2)
-        rep = drift_report(tracer)
-        assert rep["schedule"] == "1f1b"
-        assert rep["bubble"]["measured"] == pytest.approx(p.bubble_frac)
-        assert rep["bubble"]["drift"] == pytest.approx(0.0)
-        sc = rep["stage_cost_model"]
-        assert len(sc["rel_err"]) == p.n_chunks
-        # uniform synthetic profile + uniform fake durations: shares
-        # match, so per-stage relative error is ~0
-        assert sc["max_abs_rel_err"] == pytest.approx(0.0, abs=1e-9)
-        assert sum(rep["staleness"]["realized"]["fwd"].values()) == \
-            p.round_microbatches * p.n_chunks
-        txt = format_drift(rep)
-        assert all(line.startswith("#") for line in txt.splitlines())
-        assert "drift" in txt and "rel_err" in txt
-
-    def test_stream_probe_path(self):
-        cfg = tiny_cfg("granite-8b", n_layers=4, pipe=2)
-        model = Model(cfg)
-        p = plan(cfg, n_stages=2, schedule="stream", batch=4, seq=16)
-        tracer = PipelineTracer(p, clock=FakeClock())
-        assert not tracer.is_round
-        k = jax.random.PRNGKey(1)
-        batch = {"tokens": jax.random.randint(k, (4, 16), 0,
-                                              cfg.vocab_size),
-                 "targets": jax.random.randint(k, (4, 16), 0,
-                                               cfg.vocab_size)}
-        sds = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
-        state = pipeline_stream.init_state(
-            model, jax.random.PRNGKey(0), sds, plan=p)
-        costs = probe_stage_costs(model, state["params"]["stages"],
-                                  mb=2, seq=16)
-        assert len(costs) == 2 and all(c > 0 for c in costs)
-        tracer.set_probed(costs)
-        step = tracer.wrap_step(jax.jit(pipeline_stream.make_train_step(
-            model, mode="spectrain", lr=0.05, plan=p),
-            donate_argnums=0))
-        for _ in range(3):
-            state, _ = step(state, batch)
-        rep = drift_report(tracer)
-        assert rep["steps_recorded"] == 3
-        assert rep["stage_cost_model"]["measured_s"] == costs
-        obj = trace_events(tracer)
-        assert validate_trace(obj) == []
-
-    def test_stream_requires_probe_for_stage_costs(self):
-        cfg = tiny_cfg("granite-8b", n_layers=4, pipe=2)
-        p = plan(cfg, n_stages=2, schedule="stream", batch=4, seq=16)
-        tracer = PipelineTracer(p, clock=FakeClock())
-        tracer.step_walls.append(1.0)
-        with pytest.raises(ValueError, match="probe"):
-            tracer.measured_stage_costs()
 
 
 class TestMetricsRegistry:
@@ -273,41 +58,3 @@ class TestMetricsRegistry:
         assert json.loads(json.dumps(rec))["loss"] == 1.2345
         assert reg.find("train_step")[0]["step"] == 10
         assert reg.counter("train/steps_logged").value == 1
-
-    def test_kernel_hook(self):
-        reg = MetricsRegistry()
-        from repro.kernels import ops
-        ops.set_timing_hook(reg.kernel_hook())
-        try:
-            import jax.numpy as jnp
-            b, s, h, hd = 1, 4, 2, 4
-            k = jax.random.PRNGKey(0)
-            r = jax.random.normal(k, (b, s, h, hd))
-            u = jnp.zeros((h, hd))
-            S0 = jnp.zeros((b, h, hd, hd))
-            ops.rwkv6_scan(r, r, r, jnp.full_like(r, -1.0), u, S0,
-                           chunk=2, interpret=True)
-            snap = reg.histogram("kernel/rwkv6_scan_us").snapshot()
-            assert snap["count"] == 1 and snap["mean"] > 0
-        finally:
-            ops.set_timing_hook(None)
-
-    def test_kernel_hook_noop_inside_jit(self):
-        reg = MetricsRegistry()
-        from repro.kernels import ops
-        ops.set_timing_hook(reg.kernel_hook())
-        try:
-            import jax.numpy as jnp
-            b, s, h, hd = 1, 4, 2, 4
-            k = jax.random.PRNGKey(0)
-            r = jax.random.normal(k, (b, s, h, hd))
-            u = jnp.zeros((h, hd))
-            S0 = jnp.zeros((b, h, hd, hd))
-            f = jax.jit(lambda *a: ops.rwkv6_scan(*a, chunk=2,
-                                                  interpret=True))
-            f(r, r, r, jnp.full_like(r, -1.0), u, S0)
-            # traced call must not try to block on tracers (and records
-            # nothing — jit hides per-call timing)
-            assert reg.histogram("kernel/rwkv6_scan_us").count == 0
-        finally:
-            ops.set_timing_hook(None)
