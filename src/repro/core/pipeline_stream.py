@@ -23,7 +23,11 @@ across pipeline stages, exactly the paper's hybrid.
 Backward uses stored stage *inputs* plus recompute (remat), so the rings
 hold one activation tensor per (stage, in-flight microbatch) — the same
 memory PipeDream's activation stashing pays, and ~L× less than storing
-residuals.
+residuals.  The exception is a stage whose backward runs in the tick of
+its own forward and at that forward's weights (the last stage, unless
+``pipedream``; :func:`forward_reuse_stages`): it takes its vjp in the
+forward, with every residual kept, and its backward applies that vjp
+instead of running the forward again.
 
 Stage parameters are **ragged per-stage trees**: ``state["params"]
 ["stages"]`` is a tuple of ``S`` pytrees whose ``layers`` leaves are
@@ -159,13 +163,33 @@ def _per_stage_gather(ring, idx_vec):
     return jax.tree.map(leaf, ring)
 
 
-def _predict_stages(stage_trees, mom_trees, lr, s_fwd_v):
-    """Eq. 4 per stage tree with that stage's (python int) distance."""
+def _predict_stages(stage_trees, mom_trees, lr, s_fwd_v, skip=()):
+    """Eq. 4 per stage tree with that stage's (python int) distance;
+    ``None`` for the stages in ``skip``."""
     out = []
     for k, (w, v, s) in enumerate(zip(stage_trees, mom_trees, s_fwd_v)):
+        if k in skip:
+            out.append(None)
+            continue
         with phases.stage(k):
             out.append(st.predict_weights(w, v, lr, s))
     return tuple(out)
+
+
+def forward_reuse_stages(S: int, mode: str, plan=None) -> Tuple[int, ...]:
+    """Stages whose backward applies the vjp their own forward took.
+
+    Such a stage runs its backward in the tick of its forward
+    (``fb_gap`` 0, so the stashed input is the one the forward just
+    took) and at the weights that forward read: the current ones, in
+    ``vanilla`` mode or under a prediction of distance 0.  ``pipedream``
+    reads its backward weights from the weight stash, so none of its
+    stages qualifies.  For the stream schedule this is the last stage."""
+    if S == 1 or mode == "pipedream":
+        return ()
+    s_fwd, _lag, gap = _plan_vectors(S, plan)
+    return tuple(k for k in range(S)
+                 if gap[k] == 0 and (mode == "vanilla" or s_fwd[k] == 0))
 
 
 def make_state(model, params, batch_sds, *, mode: str = "spectrain",
@@ -264,13 +288,18 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
     supplies the IR-derived prediction distances and ring offsets in
     place of the closed-form constants, and its partition (validated by
     ``make_state``) determines the ragged stage trees this step
-    executes."""
+    executes.
+
+    The stages of :func:`forward_reuse_stages` run their forward once:
+    at the current weights (cast to ``bwd_dtype`` where set), without
+    remat, keeping the vjp that their backward applies."""
     assert mode in MODES, mode
     fused_predict = fused_predict and mode == "spectrain"
     S = model.n_stages
     s_fwd_v, bwd_lag, fb_gap = _plan_vectors(S, plan)
     if plan is not None:
         stage_sizes(model, plan)   # fail fast on an unexecutable plan
+    reuse = forward_reuse_stages(S, mode, plan)
     R = max(max(bwd_lag), max(fb_gap)) + 1
     s_fwd_embed = float(s_fwd_v[0])
     g_vec = jnp.array(fb_gap, jnp.int32)       # stash gather offsets
@@ -280,6 +309,16 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
     def stage_fn(sp, xk):
         xk, aux = model.stage_apply(sp, (xk, jnp.zeros((), jnp.float32)))
         return xk, aux
+
+    def stage_fn_keep(sp, xk):
+        return model.stage_apply(sp, (xk, jnp.zeros((), jnp.float32)),
+                                 remat="none")
+
+    def bwd_weights(tree):
+        if bwd_dtype is None:
+            return tree
+        bdt = jnp.dtype(bwd_dtype)
+        return jax.tree.map(lambda p: p.astype(bdt), tree)
 
     # ------------------------------------------------------------- S == 1
     def step_degenerate(state, batch):
@@ -312,7 +351,8 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
             outer_embed_f = state["pred"]["outer"]
         elif mode == "spectrain":
             with phases.scope("predict"):
-                stages_f = _predict_stages(stages, mom_stages, lr, s_fwd_v)
+                stages_f = _predict_stages(stages, mom_stages, lr, s_fwd_v,
+                                           skip=reuse)
                 outer_embed_f = st.predict_weights(outer, mom_outer, lr,
                                                    s_fwd_embed)
         else:
@@ -323,10 +363,15 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
             x_new = model.embed(outer_embed_f, batch)
             A = state["fwd_buf"].at[0].set(x_new)
             A = shard_act(A, "stage", "act_batch", None, None)
-            outs = []
+            outs, kept_vjp = [], {}
             for k in range(S):
                 with phases.stage(k):
-                    outs.append(stage_fn(stages_f[k], A[k]))
+                    if k in reuse:
+                        o, kept_vjp[k] = jax.vjp(
+                            stage_fn_keep, bwd_weights(stages[k]), A[k])
+                        outs.append(o)
+                    else:
+                        outs.append(stage_fn(stages_f[k], A[k]))
             out = jnp.stack([o for o, _aux in outs])
 
         with phases.scope("transfer"):
@@ -359,14 +404,14 @@ def make_train_step(model, *, mode: str = "spectrain", lr: float,
                                  for k in range(S))
             else:
                 stages_b = stages
-            if bwd_dtype is not None:
-                bdt = jnp.dtype(bwd_dtype)
-                stages_b = tuple(jax.tree.map(lambda p: p.astype(bdt), t_)
-                                 for t_ in stages_b)
+            stages_b = tuple(None if k in reuse else bwd_weights(t_)
+                             for k, t_ in enumerate(stages_b))
             gW, gXs = [], []
             for k in range(S):
                 with phases.stage(k):
-                    _, vjp_k = jax.vjp(stage_fn, stages_b[k], X_b[k])
+                    vjp_k = kept_vjp.get(k)
+                    if vjp_k is None:
+                        _, vjp_k = jax.vjp(stage_fn, stages_b[k], X_b[k])
                     gw_k, gx_k = vjp_k((B_cot[k], aux_cot[k]))
                 gW.append(gw_k)
                 gXs.append(gx_k)

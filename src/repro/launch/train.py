@@ -226,6 +226,10 @@ def setup(args) -> Setup:
               f"microbatches, bubble={pplan.bubble_frac:.3f}, "
               f"act_stash={pplan.act_stash}, "
               f"w_stash_depth={pplan.w_stash_depth}")
+    if schedule == "stream" and args.mode != "sync":
+        reuse = pipeline_stream.forward_reuse_stages(
+            model.n_stages, args.mode, pplan)
+        print(f"# stream: backward reuses the forward of stages {reuse}")
     return Setup(rc, cfg, model, data, batch_sds, pplan, schedule)
 
 

@@ -238,29 +238,34 @@ class Model:
         return specs_to_axes(self.param_specs())
 
     # ------------------------------------------------------------ stage apply
-    def _layer_body(self, *, pos_offset: int = 0):
+    def _layer_body(self, *, pos_offset: int = 0,
+                    remat: Optional[str] = None):
         cfg = self.cfg
+        remat = cfg.remat if remat is None else remat
 
         def body(carry, layer_p):
             x, aux = carry
             x, a, _, _ = block_apply(cfg, layer_p, x, pos_offset=pos_offset)
             return (x, aux + a), None
 
-        if cfg.remat == "full":
+        if remat == "full":
             body = jax.checkpoint(body)
-        elif cfg.remat == "dots":
+        elif remat == "dots":
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.checkpoint_dots)
         return body
 
-    def stage_apply(self, stage_params, carry, *, pos_offset: int = 0):
+    def stage_apply(self, stage_params, carry, *, pos_offset: int = 0,
+                    remat: Optional[str] = None):
         """One pipeline stage: its blocks (+ hybrid shared block).
 
         The layer count is read off the param tree's leading axis, so the
         same code executes uniform stages and ragged (plan-partitioned)
-        stages.  carry = (x [b,s,d], aux scalar)."""
+        stages.  carry = (x [b,s,d], aux scalar).  ``remat`` overrides
+        ``cfg.remat`` for the layer bodies (``"none"`` keeps every
+        residual: a caller whose backward follows at once)."""
         cfg = self.cfg
-        body = self._layer_body(pos_offset=pos_offset)
+        body = self._layer_body(pos_offset=pos_offset, remat=remat)
         layers = stage_params["layers"]
         if not self.hybrid:
             carry, _ = jax.lax.scan(body, carry, layers)

@@ -172,6 +172,47 @@ def test_runtime_names_its_phases(name, request):
     assert got["both"] == []
 
 
+def test_stream_last_stage_backward_applies_its_forward_vjp():
+    """The last stage takes its vjp under ``forward/stage1`` and applies
+    it under ``backward/stage1``: its backward recomputes no forward
+    (no ``jvp()`` outside a transpose there) and it predicts nothing."""
+    rt, state, batch, _ = _runtime("stream", "spectrain", "spmd")
+    rt.train_step(state, batch)
+    text = rt.compiled_step().as_text()
+    names = phases._OP_NAME.findall(text)
+
+    def under(prefix):
+        return [n.split(prefix, 1)[1] for n in names if prefix in n]
+
+    fwd, bwd = under("/forward/stage1/"), under("/backward/stage1/")
+    assert any(n.startswith("jvp()/") for n in fwd)
+    assert any(n.startswith("transpose(jvp())/") for n in bwd)
+    assert not [n for n in bwd if n.startswith("jvp()")]
+    assert not under("/predict/stage1/")
+    # stage 0 keeps its remat'd backward
+    assert any(n.startswith("jvp()/") for n in under("/backward/stage0/"))
+
+    # an instruction's phases take in what fused into it; its own
+    # scope is always among them
+    table = phases.op_phases(text, phases.scope_of)
+    seen = {"jvp": 0, "transpose": 0}
+    for line in text.splitlines():
+        m, n = phases._INSTR.match(line), phases._OP_NAME.search(line)
+        if not (m and n):
+            continue
+        got = table[m.group(1)]
+        if "/forward/stage1/jvp()/" in n.group(1):
+            seen["jvp"] += 1
+            assert phases.phase_of(n.group(1)) == "forward"
+            assert "forward/stage1" in got
+            assert not any(g.startswith("backward") for g in got), got
+        elif "/backward/stage1/transpose(jvp())/" in n.group(1):
+            seen["transpose"] += 1
+            assert phases.phase_of(n.group(1)) == "backward"
+            assert "backward/stage1" in got
+    assert min(seen.values()) > 0, seen
+
+
 def test_runtime_registers_on_first_step():
     rt, state, batch, _ = _runtime("1f1b", "spectrain", "spmd")
     with pytest.raises(ValueError, match="has not run"):

@@ -17,6 +17,9 @@ TPU library, so nothing may do it while modules are imported), and the
 persistent compile cache is off around the compiles: an entry written
 for a described chip cannot be read back without one.
 """
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,11 +28,13 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 import chip_smoke
+from bench import flops
 from repro.api import Runtime
 from repro.kernels import ops
 from repro.launch import train
 from repro.runtime.sharding import mpmd_state_shardings
 
+ROOT = Path(__file__).resolve().parents[1]
 GiB = 2 ** 30
 # HBM a program may use on one v5e chip, as the compiler reports it
 # ("Used ... of 15.75G hbm") when it refuses a program
@@ -108,11 +113,24 @@ def _param_bytes(model):
                    model.init, jax.random.PRNGKey(0))))
 
 
+@pytest.fixture(scope="module")
+def one_chip_phase(topo):
+    """``phase -> (compiled, run)`` of a one-chip smoke phase, each
+    compiled once."""
+    done = {}
+
+    def get(phase):
+        if phase not in done:
+            argv = chip_smoke.phase_argv(phase, batch=chip_smoke.BATCH,
+                                         seq=chip_smoke.SEQ, steps=1)
+            done[phase] = compile_step(argv, topo.devices[:1])
+        return done[phase]
+    return get
+
+
 @pytest.mark.parametrize("phase", ["A", "B"])
-def test_one_chip_phase_compiles_at_full_width(topo, phase):
-    argv = chip_smoke.phase_argv(phase, batch=chip_smoke.BATCH,
-                                 seq=chip_smoke.SEQ, steps=1)
-    compiled, run = compile_step(argv, topo.devices[:1])
+def test_one_chip_phase_compiles_at_full_width(one_chip_phase, phase):
+    compiled, run = one_chip_phase(phase)
     assert (run.cfg.d_model, run.cfg.d_ff, run.cfg.vocab_size) \
         == (4096, 14336, 49152)
     # float32 params and momentum enter the donated step whole
@@ -120,6 +138,21 @@ def test_one_chip_phase_compiles_at_full_width(topo, phase):
     assert mem.argument_size_in_bytes >= 2 * _param_bytes(run.model)
     assert mem.alias_size_in_bytes >= 2 * _param_bytes(run.model)
     assert fits_one_chip(compiled)
+
+
+def test_stream_tick_computes_little_beyond_the_model(one_chip_phase):
+    """Phase A's tick (the stream schedule, two stages of one layer)
+    recomputes stage 0's forward in its backward and nothing of the last
+    stage's, whose backward applies the vjp of its forward: the compiled
+    count stays within 1.12x the model's FLOPs (1.19x when the last
+    stage ran its forward twice)."""
+    compiled, run = one_chip_phase("A")
+    cfg = json.loads((ROOT / "bench" / "configs" / "granite-8b.json")
+                     .read_text())
+    tokens = chip_smoke.BATCH * chip_smoke.SEQ
+    model = flops.train_flops_per_token(cfg, run.cfg.n_layers,
+                                        chip_smoke.SEQ) * tokens
+    assert compiled.cost_analysis()["flops"] <= 1.12 * model
 
 
 def test_mpmd_round_compiles_on_four_chips(topo):
