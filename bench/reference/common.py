@@ -1,4 +1,5 @@
-"""Plain float32 pieces shared by the reference blocks.
+"""Plain float32 pieces shared by the reference blocks (the block
+files ``gqa.py``, ``mla.py``) and the reference's embedding and head.
 
 Nothing here imports the program.  Every matrix product goes through
 :func:`dot` / :func:`einsum`, which run at the precision the caller set
@@ -72,18 +73,28 @@ def causal_attention(q, k, v, lowp: bool, block: int = 1024):
     return jnp.concatenate(outs, 1)
 
 
+def swiglu_shapes(cfg: dict) -> dict:
+    """The leaves of a pre-norm SwiGLU layer outside its attention:
+    both norm scales and the MLP."""
+    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
+    return {("ln1", "scale"): (d,), ("ln2", "scale"): (d,),
+            ("mlp", "wg"): (d, ff), ("mlp", "w1"): (d, ff),
+            ("mlp", "w2"): (ff, d)}
+
+
 def swiglu(p, h, lowp: bool):
     return dot(jax.nn.silu(dot(h, p["wg"], lowp)) * dot(h, p["w1"], lowp),
                p["w2"], lowp)
 
 
-def embed(tok, tokens, d: int):
-    return jnp.take(tok, tokens, 0) * math.sqrt(d)
+def embed(tok, tokens, scale: float):
+    return jnp.take(tok, tokens, 0) * scale
 
 
-def head_loss(x, ln_f, w_out, targets, eps: float, lowp: bool):
-    """Mean token cross-entropy of ``rmsnorm(x) @ w_out`` over every
-    row of ``w_out``'s vocabulary."""
-    logits = dot(rmsnorm(x, ln_f, eps), w_out, lowp)
+def head_loss(x, ln_f, w_out, targets, eps: float, scale: float,
+              lowp: bool):
+    """Mean token cross-entropy of ``(rmsnorm(x) * scale) @ w_out``
+    over every row of ``w_out``'s vocabulary."""
+    logits = dot(rmsnorm(x, ln_f, eps) * scale, w_out, lowp)
     gold = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
     return jnp.mean(jax.nn.logsumexp(logits, -1) - gold)

@@ -12,6 +12,21 @@ import jax.numpy as jnp
 from bench.reference import common as c
 
 
+def layer_shapes(cfg: dict) -> dict:
+    d, H = cfg["hidden_size"], cfg["num_attention_heads"]
+    qr, kvr = cfg["q_lora_rank"], cfg["kv_lora_rank"]
+    nope, rope = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+    vd = cfg["v_head_dim"]
+    return {**c.swiglu_shapes(cfg),
+            ("attn", "w_dq"): (d, qr),
+            ("attn", "q_norm", "scale"): (qr,),
+            ("attn", "w_uq"): (qr, H * (nope + rope)),
+            ("attn", "w_dkv"): (d, kvr + rope),
+            ("attn", "kv_norm", "scale"): (kvr,),
+            ("attn", "w_ukv"): (kvr, H * (nope + vd)),
+            ("attn", "wo"): (H * vd, d)}
+
+
 def layer(cfg: dict, p: dict, x, lowp: bool = False):
     b, s, _ = x.shape
     H = cfg["num_attention_heads"]
@@ -34,3 +49,26 @@ def layer(cfg: dict, p: dict, x, lowp: bool = False):
                            jnp.concatenate([k_nope, k_rope], -1), v, lowp)
     x = x + c.dot(o.reshape(b, s, H * vd), a["wo"], lowp)
     return x + c.swiglu(p["mlp"], c.rmsnorm(x, p["ln2"]["scale"], eps), lowp)
+
+
+def layer_matmul_params(cfg: dict) -> int:
+    d, ff, H = (cfg["hidden_size"], cfg["intermediate_size"],
+                cfg["num_attention_heads"])
+    qr, kvr = cfg["q_lora_rank"], cfg["kv_lora_rank"]
+    nope, rope, vd = (cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+                      cfg["v_head_dim"])
+    return (3 * d * ff + d * qr + qr * H * (nope + rope) + d * (kvr + rope)
+            + kvr * H * (nope + vd) + H * vd * d)
+
+
+def attention_fwd_flops(cfg: dict, seq: int) -> float:
+    """Causal: each query sees ``seq / 2`` keys on average."""
+    dq = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    dv = cfg["v_head_dim"]
+    return 2 * (seq / 2) * cfg["num_attention_heads"] * (dq + dv)
+
+
+def smoke(cfg: dict, s) -> dict:
+    return {k: getattr(s.mla, k) for k in (
+        "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+        "qk_rope_head_dim", "v_head_dim")}

@@ -20,7 +20,9 @@ Stage ``k`` lives on ``devices[k]`` (one device for every stage where
 one is given); activations and cotangents move between them by
 ``jax.device_put``.  Rows are taken a few at a time, so the full batch's
 activations never sit on a device at once.  Nothing here imports the
-program; the weights and tokens are drawn again by ``bench.weights``.
+program; the weights and tokens are drawn again by ``bench.weights``,
+and the layer and the embedding's and head's scales come from the
+configuration's block file (``bench.cells.block``).
 
 ``lowp`` computes every matrix product from operands rounded to float8
 (the control).  ``fault`` plants one of the faults a timed step can
@@ -37,11 +39,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import cells
 from bench import weights as wt
 from bench.reference import common as c
-from bench.reference import gqa, mla
 
-BLOCKS = {"gqa": gqa.layer, "mla": mla.layer}
 FAULTS = ("half_batch", "no_exchange", "no_prediction")
 
 
@@ -72,7 +73,9 @@ class Reference:
         self.dev0, self.devh = self.devs[0], self.devs[-1]
         self._dev = {dv.id: dv for dv in self.devs}
         self.rows = max(1, 2048 // job["seq"])
-        layer = BLOCKS[cfg["block"]]
+        blk = cells.block(cells.ROOT, cfg["block"])
+        layer = blk.layer
+        emb_scale, logit_scale = blk.embed_scale(cfg), blk.logit_scale(cfg)
         eps = cfg["rms_norm_eps"]
         d = cfg["hidden_size"]
 
@@ -89,19 +92,20 @@ class Reference:
 
         def head(ln_f, w_out, x, tgt, weight):
             def f(ln_f, w_out, x):
-                return c.head_loss(x, ln_f, w_out, tgt, eps, lowp)
+                return c.head_loss(x, ln_f, w_out, tgt, eps, logit_scale,
+                                   lowp)
             loss, g = jax.value_and_grad(f, (0, 1, 2))(ln_f, w_out, x)
             return loss, jax.tree.map(lambda a: a * weight, g)
 
         def embed_grad(tokens, cot, n_rows):
             g = jnp.zeros((n_rows, d), jnp.float32)
             return g.at[tokens.reshape(-1)].add(
-                cot.reshape(-1, d) * jnp.sqrt(jnp.float32(d)))
+                cot.reshape(-1, d) * emb_scale)
 
         self._fwd = jax.jit(stage)
         self._bwd = jax.jit(stage_bwd)
         self._head = jax.jit(head)
-        self._embed = jax.jit(lambda tok, t: c.embed(tok, t, d))
+        self._embed = jax.jit(lambda tok, t: c.embed(tok, t, emb_scale))
         self._embed_grad = jax.jit(embed_grad, static_argnums=2)
         self._draw()
 

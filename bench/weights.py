@@ -1,6 +1,8 @@
 """The benchmark's own inputs: weights and tokens, both from ``--seed``.
 
-The weights are defined here, not by the program: a leaf's values are
+The weights are defined here, not by the program: the outer leaves and,
+stacked over the layers, one layer's leaves as the configuration's
+block file (``bench.cells.block``) lists them.  A leaf's values are
 normal draws with std ``1/sqrt(rows)`` (``rows`` = the second-to-last
 dimension of the leaf, per layer for stacked leaves) for every matrix,
 embedding included, and ones for every norm scale.  Each leaf draws
@@ -21,6 +23,8 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from bench import cells
+
 Path = Tuple[str, ...]
 
 
@@ -32,37 +36,6 @@ def seed_key(seed: int):
         raise ValueError(f"seed must be >= 0, got {seed}")
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
                               seed >> 31)
-
-
-def layer_shapes(cfg: dict) -> Dict[Path, tuple]:
-    """One layer's leaves (norm scales are 1-d) for ``cfg['block']``."""
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    H = cfg["num_attention_heads"]
-    out: Dict[Path, tuple] = {
-        ("ln1", "scale"): (d,), ("ln2", "scale"): (d,),
-        ("mlp", "wg"): (d, ff), ("mlp", "w1"): (d, ff),
-        ("mlp", "w2"): (ff, d),
-    }
-    if cfg["block"] == "gqa":
-        hd, KV = cfg["head_dim"], cfg["num_key_value_heads"]
-        out.update({("attn", "wq"): (d, H * hd),
-                    ("attn", "wk"): (d, KV * hd),
-                    ("attn", "wv"): (d, KV * hd),
-                    ("attn", "wo"): (H * hd, d)})
-    elif cfg["block"] == "mla":
-        qr, kvr = cfg["q_lora_rank"], cfg["kv_lora_rank"]
-        nope, rope = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
-        vd = cfg["v_head_dim"]
-        out.update({("attn", "w_dq"): (d, qr),
-                    ("attn", "q_norm", "scale"): (qr,),
-                    ("attn", "w_uq"): (qr, H * (nope + rope)),
-                    ("attn", "w_dkv"): (d, kvr + rope),
-                    ("attn", "kv_norm", "scale"): (kvr,),
-                    ("attn", "w_ukv"): (kvr, H * (nope + vd)),
-                    ("attn", "wo"): (H * vd, d)})
-    else:
-        raise ValueError(f"unknown block kind {cfg['block']!r}")
-    return out
 
 
 def outer_shapes(cfg: dict) -> Dict[Path, tuple]:
@@ -77,9 +50,9 @@ def outer_shapes(cfg: dict) -> Dict[Path, tuple]:
 def leaf_table(cfg: dict, n_layers: int):
     """``[(group, path, shape)]`` in key order: ``group`` is "outer" or
     "layers" (whose shapes carry the leading ``n_layers``)."""
+    layer = cells.block(cells.ROOT, cfg["block"]).layer_shapes(cfg)
     rows = [("outer", p, s) for p, s in outer_shapes(cfg).items()]
-    rows += [("layers", p, (n_layers,) + s)
-             for p, s in layer_shapes(cfg).items()]
+    rows += [("layers", p, (n_layers,) + s) for p, s in layer.items()]
     return sorted(rows, key=lambda r: (r[0],) + r[1])
 
 

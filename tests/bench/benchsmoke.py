@@ -36,19 +36,17 @@ FAULTS = ("unchanged", "half_batch", "no_exchange")
 
 
 def smoke_cfg(cfg: dict) -> dict:
+    """``cfg`` at the registry's smoke widths: the keys every block
+    has, and the block file's own (``smoke``)."""
+    from bench import cells
     from repro.configs import get_config, smoke_config
     s = smoke_config(get_config(cfg["repo_arch"]))
-    out = dict(cfg, hidden_size=s.d_model, intermediate_size=s.d_ff,
-               num_attention_heads=s.n_heads,
-               num_key_value_heads=s.n_kv_heads, head_dim=s.hd,
-               vocab_size=s.vocab_size, vocab_rows=s.vocab_padded)
-    if s.mla is not None:
-        out.update(q_lora_rank=s.mla.q_lora_rank,
-                   kv_lora_rank=s.mla.kv_lora_rank,
-                   qk_nope_head_dim=s.mla.qk_nope_head_dim,
-                   qk_rope_head_dim=s.mla.qk_rope_head_dim,
-                   v_head_dim=s.mla.v_head_dim)
-    return out
+    blk = cells.block(cells.ROOT, cfg["block"])
+    return dict(cfg, hidden_size=s.d_model, intermediate_size=s.d_ff,
+                num_attention_heads=s.n_heads,
+                num_key_value_heads=s.n_kv_heads,
+                vocab_size=s.vocab_size, vocab_rows=s.vocab_padded,
+                **blk.smoke(cfg, s))
 
 
 PROVEN = "granite8b-stream-1chip"

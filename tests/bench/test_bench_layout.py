@@ -74,6 +74,7 @@ def test_benchmark_configs_name_their_files():
 
 @pytest.mark.parametrize("name", CONFIG_FILES)
 def test_config_file_widths_match_registry(name):
+    from bench import cells
     from repro.configs import get_config
     cfg = _cfg(name)
     reg = get_config(cfg["repo_arch"])
@@ -84,15 +85,9 @@ def test_config_file_widths_match_registry(name):
         reg.d_model, reg.d_ff, reg.n_heads, reg.n_kv_heads,
         reg.vocab_size, reg.vocab_padded, reg.tie_embeddings)
     assert cfg["rope_theta"] == reg.rope_theta
-    if cfg["block"] == "mla":
-        m = reg.mla
-        assert (cfg["q_lora_rank"], cfg["kv_lora_rank"],
-                cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
-                cfg["v_head_dim"]) == (
-            m.q_lora_rank, m.kv_lora_rank, m.qk_nope_head_dim,
-            m.qk_rope_head_dim, m.v_head_dim)
-    else:
-        assert reg.mla is None and cfg["head_dim"] == reg.hd
+    # the block's own widths, as its file reads them from the registry
+    own = cells.block(ROOT, cfg["block"]).smoke(cfg, reg)
+    assert own and {k: cfg[k] for k in own} == own
     # the depth a cell runs is its traffic's stages x layers_per_stage
     assert "num_hidden_layers" not in cfg
     assert f"published {reg.n_layers};" in cfg["reduced"]["num_hidden_layers"]

@@ -1,10 +1,11 @@
 """The benchmark's plain float32 reference against the repo's model, at
 smoke widths on the CPU.
 
-Each block kind (``bench/reference/gqa.py``, ``mla.py``), the embedding
-and the head with its loss are run on the same weights as the model's
-own ``block_apply`` / ``embed`` / ``head_loss`` in float32 at
-``highest`` matmul precision.  Tolerance: 1e-5 of the output's scale,
+Each block kind (``bench/reference/gqa.py``, ``mla.py``, found through
+``bench.cells.block`` by the configuration's ``block`` key), the
+embedding and the head with its loss, at the block's scales, are run on
+the same weights as the model's own ``block_apply`` / ``embed`` /
+``head_loss`` in float32 at ``highest`` matmul precision.  Tolerance: 1e-5 of the output's scale,
 about a hundred float32 roundings over sums of at most a few hundred
 terms; a wrong equation (a missing norm, a RoPE on the wrong half, an
 unscaled embedding) moves the output by order one.
@@ -47,22 +48,25 @@ def _close(got, want):
 @pytest.mark.parametrize("arch", ARCHES)
 def test_block_matches_model(arch):
     from repro.models.transformer import block_apply
-    from bench.reference.train import BLOCKS
+    from bench import cells
     cfg, repo, w = _setup(arch)
+    layer = cells.block(ROOT, cfg["block"]).layer
     x = jax.random.normal(jax.random.PRNGKey(3),
                           (2, 48, cfg["hidden_size"]))
     with jax.default_matmul_precision("highest"):
         for i in range(2):
             p = jax.tree.map(lambda a: a[i], w["layers"])
             want = block_apply(repo, p, x)[0]
-            _close(BLOCKS[cfg["block"]](cfg, p, x), want)
+            _close(layer(cfg, p, x), want)
 
 
 @pytest.mark.parametrize("arch", ARCHES)
 def test_embedding_and_head_match_model(arch):
     from repro.models import Model
+    from bench import cells
     from bench.reference import common as c
     cfg, repo, w = _setup(arch)
+    blk = cells.block(ROOT, cfg["block"])
     model = Model(repo)
     toks = jax.random.randint(jax.random.PRNGKey(5), (2, 24), 0,
                               cfg["vocab_size"])
@@ -73,10 +77,10 @@ def test_embedding_and_head_match_model(arch):
     w_out = out["embed"]["tok"].T if cfg["tie_word_embeddings"] \
         else out["embed"]["unembed"]
     with jax.default_matmul_precision("highest"):
-        _close(c.embed(out["embed"]["tok"], toks, cfg["hidden_size"]),
+        _close(c.embed(out["embed"]["tok"], toks, blk.embed_scale(cfg)),
                model.embed(out, {"tokens": toks}))
         _close(c.head_loss(x, out["ln_f"]["scale"], w_out, tgts,
-                           cfg["rms_norm_eps"], False),
+                           cfg["rms_norm_eps"], blk.logit_scale(cfg), False),
                model.head_loss(out, x, tgts))
 
 
